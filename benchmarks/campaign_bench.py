@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import device
 from repro.core.dependability import Policy, dependable_qconv2d, dependable_qmatmul
 
 
@@ -190,6 +191,7 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_campaign.json",
                     help="summary JSON path ('' skips writing)")
     args = ap.parse_args(argv)
+    device.enable_compile_cache()
     backends = tuple(b.strip() for b in args.backends.split(",") if b.strip())
     reps = 5 if args.fast else 20
     qm_shape = (256, 512, 256)

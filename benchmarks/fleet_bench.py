@@ -24,6 +24,7 @@ import time
 import jax
 import numpy as np
 
+from repro import device
 from repro.core.dependability import Policy
 from repro.fleet import Fleet
 from repro.runtime.serving import Request
@@ -104,6 +105,7 @@ def main(argv=None):
                     help="write every row's full FleetMetrics snapshot "
                          "(registry counters + latency histograms) as JSON")
     args = ap.parse_args(argv)
+    device.enable_compile_cache()
 
     replica_counts = [2] if args.fast else [
         int(x) for x in args.replicas.split(",")]

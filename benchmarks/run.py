@@ -17,6 +17,8 @@ import time
 
 import numpy as np
 
+from repro import device
+
 
 def bench_table1(check: bool = True):
     print("\n=== Table 1 / Figure 5: conv+requant latency (paper repro) ===")
@@ -82,8 +84,7 @@ def bench_kernels(fast: bool = False):
     t_ref = _time(jax.jit(lambda: qmatmul_ref(x, jnp.int32(0), w, bias,
                                               scale, jnp.int32(0))))
     print(f"kernels,qmatmul_ref_{m}x256x128,us_per_call={t_ref:.0f}")
-    t_int = _time(lambda: qmatmul(x, w, colsum, bias, scale, zps,
-                                  interpret=True))
+    t_int = _time(lambda: qmatmul(x, w, colsum, bias, scale, zps))
     print(f"kernels,qmatmul_interpret_{m}x256x128,us_per_call={t_int:.0f},"
           f"derived=interpreter_overhead_{t_int/max(t_ref,1):.0f}x")
 
@@ -93,7 +94,7 @@ def bench_kernels(fast: bool = False):
     v = jnp.asarray(rng.standard_normal((1, 2, S, 32)), jnp.float32)
     t_ref = _time(jax.jit(lambda: attention_ref(q, k, v)))
     print(f"kernels,flashattn_ref_S{S},us_per_call={t_ref:.0f}")
-    t_int = _time(lambda: flash_attention(q, k, v, interpret=True,
+    t_int = _time(lambda: flash_attention(q, k, v,
                                           block_q=64, block_k=64))
     print(f"kernels,flashattn_interpret_S{S},us_per_call={t_int:.0f}")
 
@@ -127,6 +128,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    device.enable_compile_cache()
 
     t0 = time.time()
     bench_table1(check=True)

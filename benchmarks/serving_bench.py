@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import device
 from repro.configs import registry
 from repro.models import api as model_api
 from repro.models.config import reduced
@@ -155,6 +156,7 @@ def main(argv=None) -> int:
                          "(.prom extension → Prometheus text format)")
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args(argv)
+    device.enable_compile_cache()
 
     cfg = reduced(registry.get(args.arch))
     if args.quant_kv:

@@ -32,6 +32,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro import device
 from repro.models.shipdet import TABLE1_LAYERS, ConvSpec
 
 # Paper Table 1 (ms)
@@ -88,7 +89,7 @@ def correctness_check() -> bool:
         x_zp = jnp.int32(3)
         out_zp = jnp.int32(-2)
         got = ops.qconv2d_op(x_q, x_zp, w_q, colsum, bias, scale, out_zp,
-                             use_kernel=True, interpret=True)
+                             use_kernel=True)
         want = ref.qconv2d_ref(x_q, x_zp, w_q, bias, scale, out_zp)
         same = np.array_equal(np.asarray(got), np.asarray(want))
         print(f"  {s.name:<18} reduced {r.h}x{r.w}: kernel==oracle: {same}")
@@ -157,6 +158,7 @@ def main():
     ap.add_argument("--out", default="reports/table1_bitsweep",
                     help="output directory for the --bit-sweep report")
     args = ap.parse_args()
+    device.enable_compile_cache()
 
     if args.bit_sweep:
         raise SystemExit(bit_sweep(args.bit_trials, args.seed, args.out))

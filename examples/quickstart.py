@@ -35,7 +35,7 @@ out_scale, out_zp = quant.affine_qparams(float(y_float.min()),
                                          float(y_float.max()))
 
 y = kernels.qconv_act(x, params, x_scale, x_zp, out_scale, out_zp,
-                        use_kernel=True, interpret=True)
+                        use_kernel=True)
 err = float(jnp.abs(y - y_float).max())
 print(f"conv out {y.shape}, max |int8 path − float path| = {err:.4f} "
       f"(≤ a few quantization steps of {float(out_scale):.4f})")
@@ -45,7 +45,7 @@ assert err < 6 * float(out_scale)
 w2 = jnp.asarray(rng.standard_normal((3, 3, 24, 24)), jnp.float32) * 0.3
 params2 = kernels.make_qconv_params(w2, b)
 y2 = kernels.qconv_act(x, params2, x_scale, x_zp, out_scale, out_zp,
-                         use_kernel=True, interpret=True)
+                         use_kernel=True)
 print(f"second layer through the SAME kernel config: out {y2.shape} ✓")
 
 print()
@@ -58,7 +58,7 @@ lp = kernels.make_qlinear_params(wt)
 xs, xzp = quant.affine_qparams(float(xt.min()), float(xt.max()))
 os_, ozp = quant.affine_qparams(-8.0, 8.0)
 yt = kernels.qlinear_act(xt, lp, xs, xzp, os_, ozp,
-                             use_kernel=True, interpret=True)
+                             use_kernel=True)
 yt_ref = xt @ wt
 rel = float(jnp.linalg.norm(yt - yt_ref) / jnp.linalg.norm(yt_ref))
 print(f"qlinear out {yt.shape}, relative error vs float = {rel:.4f}")

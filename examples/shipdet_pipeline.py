@@ -28,8 +28,7 @@ frames = jnp.asarray(rng.standard_normal((2, specs[0].h, specs[0].w, 3)),
                      jnp.float32)
 
 t0 = time.time()
-q_out, _ = shipdet.forward(specs, params, frames, use_kernel=True,
-                           interpret=True)
+q_out, _ = shipdet.forward(specs, params, frames, use_kernel=True)
 t_q = time.time() - t0
 f_out = shipdet.float_forward(specs, params, frames)
 

@@ -122,11 +122,11 @@ _WORKER_CASES: dict = {}
 
 
 def _pool_init(src_path: str) -> None:
-    # workers are compute replicas of the parent: CPU-pinned JAX, the repo's
-    # src on the path (spawned interpreters don't inherit sys.path edits)
+    # workers are compute replicas of the (CPU-only, see CampaignPool)
+    # parent: the repo's src on the path (spawned interpreters don't inherit
+    # sys.path edits) and the parent's environment, JAX_PLATFORMS included
     if src_path and src_path not in os.sys.path:
         os.sys.path.insert(0, src_path)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax  # noqa: F401 — warm the import before the first task
 
 
@@ -147,12 +147,17 @@ class CampaignPool:
     case-build cost once and then serves chunks for the rest of the
     campaign, so per-worker state (compiled engines, golden outputs) is
     reused across configurations of the same workload.
+
+    Runs only on a CPU host: on an accelerator the parent holds the device
+    and the workers could not share it, so the pool refuses to start.
     """
 
     def __init__(self, workers: int):
         if workers < 1:
             raise ValueError("workers must be >= 1")
         import repro
+        from repro import device
+        device.forbid_child_processes("the campaign worker pool")
         # repro is a namespace package (__file__ is None): locate its src
         # root via __path__ so spawned workers can import it
         src = str(pathlib.Path(list(repro.__path__)[0]).resolve().parent)

@@ -22,9 +22,10 @@ block (faults are rare, so `lax.cond` makes the recompute cost ~0 amortized).
 
 The accumulator and check vector both come from the pluggable execution
 backend (``core.backend`` / ``kernels.dispatch``): on ``backend="pallas"``
-the check vector is fused into the kernel itself — one extra block-row
-matvec per K step — so detection covers the paper's actual co-processor
-path with no separate checksum pass (see docs/backends.md).
+the check vector is fused into the kernel itself — one extra int8 MXU
+product of the check vector's four limbs per K step — so detection covers
+the paper's actual co-processor path with no separate checksum pass (see
+docs/backends.md).
 """
 from __future__ import annotations
 
@@ -50,6 +51,29 @@ def _dot_i32(x_q: jax.Array, w_q: jax.Array) -> jax.Array:
 def checksum_vector(w_q: jax.Array) -> jax.Array:
     """W · 1_N — the column-sum check vector, precomputable per layer. (K,) i32."""
     return jnp.sum(w_q.astype(jnp.int32), axis=1)
+
+
+def int8_limbs(v: jax.Array) -> jax.Array:
+    """Split int32 ``v`` into four int8 limbs, shape ``v.shape + (4,)``, with
+    sum_l limbs[..., l] << 8l == v (mod 2^32).
+
+    The MXU multiplies int8 by int8 and nothing wider, so a kernel that
+    needs X · v for an int32 check vector v computes the four int8 products
+    X · limb_l on the MXU and recombines them with ``from_limbs``: exact,
+    because the identity holds mod 2^32 and every product is exact."""
+    v = v.astype(jnp.int32)
+    limbs = []
+    for _ in range(4):
+        d = ((v + 128) & 255) - 128                       # low byte, signed
+        limbs.append(d)
+        v = (v - d) >> 8                                  # exact mod 2^32
+    return jnp.stack(limbs, axis=-1).astype(jnp.int8)
+
+
+def from_limbs(parts: jax.Array) -> jax.Array:
+    """Inverse of ``int8_limbs`` over products: (..., 4) i32 -> (...) i32."""
+    parts = parts.astype(jnp.int32)
+    return sum(parts[..., l] << (8 * l) for l in range(4))
 
 
 def zp_bias_correct(acc_dot: jax.Array, x_zp: jax.Array, w_q: jax.Array,
@@ -84,7 +108,8 @@ def abft_qmatmul(
 
     Overhead: one (M,K)×(K,1) matvec + one row reduction ≈ 1/N of the matmul
     FLOPs (0.8 % for N=128); on ``backend="pallas"`` the matvec is fused into
-    the kernel itself (one extra block-row per K step, no second pass over X).
+    the kernel itself (four int8 limb columns per K step, no second pass
+    over X).
 
     ``w_check`` lets the caller supply the check vector computed from a known-
     good weight copy (e.g. at checkpoint load).  With it, ABFT also catches

@@ -10,8 +10,9 @@ qconv2d) registers interchangeable implementations behind one registry:
           the Fig.-4 "PyTorch reference" role
   jnp     XLA-native int8 dot_general / conv_general_dilated — the fleet
           default on CPU and the fastest path XLA fuses on its own
-  pallas  the Pallas TPU kernels (interpret=True off-TPU) — the paper's
-          actual co-processor path, including the fused ABFT checksum
+  pallas  the Pallas TPU kernels (interpreted when lowered for the CPU) —
+          the paper's actual co-processor path, including the fused ABFT
+          checksum
 
 The registry's uniform signature is **accumulator-level**: every backend
 returns the raw int32 accumulator (and, for the checksummed entry, the

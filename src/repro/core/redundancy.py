@@ -99,7 +99,7 @@ def replicated_vote(f: Callable, mesh: jax.sharding.Mesh, axis: str = "replica")
     Returns a function with the same signature as f; inputs must be
     replicated along ``axis``.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def voted(*args):

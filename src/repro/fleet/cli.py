@@ -34,6 +34,7 @@ import sys
 import jax
 import numpy as np
 
+from repro import device
 from repro.core import fault_injection as fi
 from repro.core.dependability import Policy
 from repro.fleet.fleet import FLEET_POLICIES, TRANSPORTS, Fleet
@@ -140,6 +141,7 @@ def _serve(fleet: Fleet, prompts, max_new_tokens: int, *,
 def main(argv=None) -> int:
     parser = build_parser()
     args = parser.parse_args(argv)
+    device.enable_compile_cache()
     from repro.configs import registry
     from repro.models import api as model_api
     from repro.models.config import reduced

@@ -82,6 +82,8 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from repro.core.dependability import Policy
 from repro.fleet.metrics import FleetMetrics
 from repro.obs import EventLog
@@ -199,18 +201,24 @@ class Fleet:
                 r.wait_ready()
             self._golden0 = _checksums_jit(params)
         else:
+            # replica i holds its params and decode state on device i (mod
+            # the device count): with as many chips as replicas, every
+            # replica — and each member of a DMR pair — is its own fault
+            # domain
+            devices = jax.devices()
             first = Replica(0, cfg, params, capacity=capacity,
                             max_len=max_len, prefill_pad=prefill_pad,
                             snapshot_every=snapshot_every,
                             eos_id=eos_id, backend=backend,
-                            state_scrub=scrub_mode)
+                            state_scrub=scrub_mode, device=devices[0])
             self.replicas = [first] + [
                 Replica(i, cfg, params, capacity=capacity, max_len=max_len,
                         prefill_pad=prefill_pad,
                         snapshot_every=snapshot_every,
                         eos_id=eos_id, golden=first.golden,
                         compiled=first.engine.compiled, backend=backend,
-                        state_scrub=scrub_mode)
+                        state_scrub=scrub_mode,
+                        device=devices[i % len(devices)])
                 for i in range(1, n_replicas)]
             self._golden0 = first.golden
         # the fleet's release gate runs inside each engine's certify stage;

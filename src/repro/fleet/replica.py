@@ -48,13 +48,13 @@ class Replica:
                  capacity: int = 4, max_len: int = 128, prefill_pad: int = 8,
                  snapshot_every: int = 16, eos_id: int = -1,
                  golden=None, compiled=None, backend: Optional[str] = None,
-                 state_scrub: str = "off"):
+                 state_scrub: str = "off", device=None):
         self.rid = rid
         self.engine = Engine(cfg, params, capacity=capacity, max_len=max_len,
                              prefill_pad=prefill_pad,
                              snapshot_every=snapshot_every, eos_id=eos_id,
                              compiled=compiled, backend=backend,
-                             state_scrub=state_scrub)
+                             state_scrub=state_scrub, device=device)
         self.state = ReplicaState.HEALTHY
         self.paused = False          # test hook: stop heartbeating (looks dead)
         self.routable = True         # False while a rolling deploy swaps us

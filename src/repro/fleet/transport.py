@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 import struct
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -295,24 +294,17 @@ class WorkerHandle:
 
     def spawn(self) -> None:
         import multiprocessing as mp
+        from repro import device
         from repro.fleet import worker as worker_mod
+        # a worker is a process of its own: on an accelerator the parent
+        # holds the chip and the child could not reach it, so refuse there
+        device.forbid_child_processes("the fleet's proc transport")
         ctx = mp.get_context("spawn")      # never fork a live XLA runtime
         parent_conn, child_conn = ctx.Pipe(duplex=True)
-        # pin the child's platform to the parent's before the spawn snapshot
-        # of os.environ is taken, so the worker cannot race the parent for
-        # an accelerator it was not meant to share
-        unset = "JAX_PLATFORMS" not in os.environ
-        if unset:
-            import jax
-            os.environ["JAX_PLATFORMS"] = jax.default_backend()
-        try:
-            self.proc = ctx.Process(
-                target=worker_mod.worker_entry, args=(child_conn, self.rid),
-                name=f"fleet-worker-{self.rid}", daemon=True)
-            self.proc.start()
-        finally:
-            if unset:
-                del os.environ["JAX_PLATFORMS"]
+        self.proc = ctx.Process(
+            target=worker_mod.worker_entry, args=(child_conn, self.rid),
+            name=f"fleet-worker-{self.rid}", daemon=True)
+        self.proc.start()
         child_conn.close()
         self.ch = PipeChannel(parent_conn, f"worker{self.rid}")
         self.dead = False

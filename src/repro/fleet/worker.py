@@ -3,8 +3,9 @@
 ``worker_entry`` is the spawn target: it owns one real ``Replica`` (engine,
 weights, golden checksums) and serves the parent's framed RPCs over a
 ``PipeChannel``.  The module top stays import-light — the heavy imports
-(jax, the model stack) happen inside ``worker_entry`` *after* the spawn, so
-the parent can stamp ``JAX_PLATFORMS`` into the child's environment first.
+(jax, the model stack) happen inside ``worker_entry`` *after* the spawn.
+Workers run only under a CPU parent (``repro.device.forbid_child_processes``)
+and inherit its environment.
 
 Certify-before-release crosses the boundary as an *upcall*: the worker
 installs a certifier on its replica that sends the finished request to the

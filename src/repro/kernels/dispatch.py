@@ -29,10 +29,6 @@ from repro.kernels.qmatmul.kernel import (
     qmatmul_acc_checksum as qmatmul_acc_checksum_pallas)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # jnp — XLA-native int8 dot / conv (the historical inlined path)
 # ---------------------------------------------------------------------------
@@ -134,18 +130,17 @@ def _conv_acc_checksum_ref(x_q, x_zp, w_q, w_check, stride, padding):
 
 
 # ---------------------------------------------------------------------------
-# pallas — the co-processor path (interpret=True off-TPU, per the paper's
-# simulator-stands-in-for-hardware methodology)
+# pallas — the co-processor path (interpreted when lowered for the CPU, per
+# the paper's simulator-stands-in-for-hardware methodology; repro.device)
 # ---------------------------------------------------------------------------
 
 
 def _matmul_acc_pallas(x_q, w_q):
-    return qmatmul_acc_pallas(x_q, w_q, interpret=not _on_tpu())
+    return qmatmul_acc_pallas(x_q, w_q)
 
 
 def _matmul_acc_checksum_pallas(x_q, w_q, w_check):
-    return qmatmul_acc_checksum_pallas(x_q, w_q, w_check,
-                                       interpret=not _on_tpu())
+    return qmatmul_acc_checksum_pallas(x_q, w_q, w_check)
 
 
 def _pad_zp(x_q, x_zp, pads):
@@ -166,8 +161,7 @@ def _conv_acc_pallas(x_q, x_zp, w_q, stride, padding):
     xp = _pad_zp(x_q, x_zp, pads)
     colsum = jnp.sum(w_q.astype(jnp.int32), axis=(0, 1, 2))
     zp = x_zp.astype(jnp.int32).reshape(1)
-    return qconv2d_acc_pallas(xp, w_q, colsum, zp, stride=stride,
-                              interpret=not _on_tpu())
+    return qconv2d_acc_pallas(xp, w_q, colsum, zp, stride=stride)
 
 
 def _conv_acc_checksum_pallas(x_q, x_zp, w_q, w_check, stride, padding):
@@ -178,8 +172,7 @@ def _conv_acc_checksum_pallas(x_q, x_zp, w_q, w_check, stride, padding):
     colsum = jnp.sum(w_q.astype(jnp.int32), axis=(0, 1, 2))
     zp = x_zp.astype(jnp.int32).reshape(1)
     return qconv2d_acc_checksum_pallas(xp, w_q, colsum, w_check, zp,
-                                       stride=stride,
-                                       interpret=not _on_tpu())
+                                       stride=stride)
 
 
 # ---------------------------------------------------------------------------
@@ -259,14 +252,12 @@ def _attn_checksum_ref(q, k, v, *, causal=True, window=None):
 
 
 def _attn_pallas(q, k, v, *, causal=True, window=None):
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  interpret=not _on_tpu())
+    return flash_attention_pallas(q, k, v, causal=causal, window=window)
 
 
 def _attn_checksum_pallas(q, k, v, *, causal=True, window=None):
     return flash_attention_checked_pallas(q, k, v, causal=causal,
-                                          window=window,
-                                          interpret=not _on_tpu())
+                                          window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +292,7 @@ for _be in (
         attn=_attn_pallas,
         attn_checksum=_attn_checksum_pallas,
         description="Pallas TPU kernels with fused ABFT checksum "
-                    "(interpret=True off-TPU)"),
+                    "(interpreted when lowered for the CPU)"),
 ):
     backend_mod.register_backend(_be, overwrite=True)
 del _be

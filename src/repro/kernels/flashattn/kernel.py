@@ -31,10 +31,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.device import pallas_call
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -102,7 +101,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret"))
+    "causal", "window", "block_q", "block_k"))
 def flash_attention(
     q: jax.Array,            # (B, H, S, hd)
     k: jax.Array,            # (B, KV, S, hd)
@@ -112,7 +111,6 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
 ) -> jax.Array:
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -136,7 +134,7 @@ def flash_attention(
     kr = k.reshape(B * KV, S, hd)
     vr = v.reshape(B * KV, S, hd)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_flash_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k,
                           window=window, causal=causal),
@@ -153,10 +151,9 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, H, S, hd)
 
@@ -244,17 +241,19 @@ def _flash_checked_kernel(q_ref, k_ref, v_ref, o_ref, chk_ref, csum_ref,
         l = jnp.maximum(l_ref[...], 1e-30)
         out = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
         o_ref[0] = out
-        chk_ref[0] = c_ref[...] / l
+        chk_ref[0, 0] = c_ref[...] / l
+        # the bit patterns are summed as int32 (Mosaic reduces no unsigned
+        # type); two's-complement wrap makes it the same sum mod 2^32
         if out.dtype == jnp.float32:
-            bits = jax.lax.bitcast_convert_type(out, jnp.uint32)
+            bits = jax.lax.bitcast_convert_type(out, jnp.int32)
         else:                                             # bf16 / f16 I/O
-            bits = jax.lax.bitcast_convert_type(out, jnp.uint16).astype(
-                jnp.uint32)
-        csum_ref[0] = jnp.sum(bits, axis=-1)              # wraps mod 2^32
+            bits = jax.lax.bitcast_convert_type(out, jnp.int16).astype(
+                jnp.int32) & 0xFFFF
+        csum_ref[0, 0] = jnp.sum(bits, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret"))
+    "causal", "window", "block_q", "block_k"))
 def flash_attention_checked(
     q: jax.Array,            # (B, H, S, hd)
     k: jax.Array,            # (B, KV, S, hd)
@@ -264,7 +263,6 @@ def flash_attention_checked(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
 ):
     """Forward attention returning ``(out, check, csum)``.
 
@@ -291,12 +289,12 @@ def flash_attention_checked(
         return (b * KV + h // G, ki, 0)
 
     def row_map(bh, qi, ki):
-        return (bh, qi)
+        return (bh, 0, qi)
 
     qr = q.reshape(B * H, S, hd)
     kr = k.reshape(B * KV, S, hd)
     vr = v.reshape(B * KV, S, hd)
-    out, check, csum = pl.pallas_call(
+    out, check, csum = pallas_call(
         functools.partial(_flash_checked_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k,
                           window=window, causal=causal),
@@ -307,22 +305,22 @@ def flash_attention_checked(
             pl.BlockSpec((1, block_k, hd), kv_map),
         ],
         out_specs=[pl.BlockSpec((1, block_q, hd), q_map),
-                   pl.BlockSpec((1, block_q), row_map),
-                   pl.BlockSpec((1, block_q), row_map)],
+                   pl.BlockSpec((1, 1, block_q), row_map),
+                   pl.BlockSpec((1, 1, block_q), row_map)],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.float32),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.uint32)],
+                   jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32),
+                   jax.ShapeDtypeStruct((B * H, 1, S), jnp.int32)],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
     )(qr, kr, vr)
+    csum = jax.lax.bitcast_convert_type(csum, jnp.uint32)
     return (out.reshape(B, H, S, hd), check.reshape(B, H, S),
             csum.reshape(B, H, S))
 
@@ -395,7 +393,7 @@ def _flash_fwd_lse_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _epilogue():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _recompute_p(q, k, lse_rows, q_lo, k_lo, *, scale, seq_len, block_q,
@@ -445,14 +443,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         krow = k_lo + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
         k = jnp.where(krow < seq_len, k, 0.0)
         v = jnp.where(krow < seq_len, v, 0.0)
-        p = _recompute_p(q, k, lse_ref[0], q_lo, k_lo, scale=scale,
+        p = _recompute_p(q, k, lse_ref[0, 0], q_lo, k_lo, scale=scale,
                          seq_len=seq_len, block_q=block_q, block_k=block_k,
                          window=window, causal=causal)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         # q-tail rows: OOB dvec/lse are undefined; 0·NaN = NaN would leak
         qrow1 = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-        dvec = jnp.where(qrow1 < seq_len, dvec_ref[0], 0.0)
+        dvec = jnp.where(qrow1 < seq_len, dvec_ref[0, 0], 0.0)
         ds = p * (dp - dvec[:, None]) * scale
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -494,7 +492,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         qrow = q_lo + jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
         q = jnp.where(qrow < seq_len, q, 0.0)
         do = jnp.where(qrow < seq_len, do, 0.0)
-        p = _recompute_p(q, k, lse_ref[0], q_lo, k_lo, scale=scale,
+        p = _recompute_p(q, k, lse_ref[0, 0], q_lo, k_lo, scale=scale,
                          seq_len=seq_len, block_q=block_q, block_k=block_k,
                          window=window, causal=causal)
         dv_acc[...] += jax.lax.dot_general(
@@ -505,7 +503,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         # q-tail rows: OOB dvec is undefined; 0·NaN would poison the
         # q-contraction in dk below
         qrow1 = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-        dvec = jnp.where(qrow1 < seq_len, dvec_ref[0], 0.0)
+        dvec = jnp.where(qrow1 < seq_len, dvec_ref[0, 0], 0.0)
         ds = p * (dp - dvec[:, None]) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
@@ -518,9 +516,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret"))
+    "causal", "window", "block_q", "block_k"))
 def flash_attention_fwd_lse(q, k, v, *, causal=True, window=None,
-                            block_q=128, block_k=128, interpret=False):
+                            block_q=128, block_k=128):
     """Forward returning (out, lse); layout as flash_attention."""
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -539,12 +537,12 @@ def flash_attention_fwd_lse(q, k, v, *, causal=True, window=None,
         return (b * KV + h // G, ki, 0)
 
     def lse_map(bh, qi, ki):
-        return (bh, qi)
+        return (bh, 0, qi)
 
     qr = q.reshape(B * H, S, hd)
     kr = k.reshape(B * KV, S, hd)
     vr = v.reshape(B * KV, S, hd)
-    out, lse = pl.pallas_call(
+    out, lse = pallas_call(
         functools.partial(_flash_fwd_lse_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal),
@@ -553,23 +551,22 @@ def flash_attention_fwd_lse(q, k, v, *, causal=True, window=None,
                   pl.BlockSpec((1, block_k, hd), kv_map),
                   pl.BlockSpec((1, block_k, hd), kv_map)],
         out_specs=[pl.BlockSpec((1, block_q, hd), q_map),
-                   pl.BlockSpec((1, block_q), lse_map)],
+                   pl.BlockSpec((1, 1, block_q), lse_map)],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.float32)],
+                   jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, H, S, hd), lse.reshape(B, H, S)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "interpret"))
+    "causal", "window", "block_q", "block_k"))
 def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
-                        block_q=128, block_k=128, interpret=False):
+                        block_q=128, block_k=128):
     """Returns (dq, dk, dv). q (B,H,S,hd), k/v (B,KV,S,hd)."""
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -586,8 +583,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
     kr = k.reshape(B * KV, S, hd)
     vr = v.reshape(B * KV, S, hd)
     dor = do.reshape(B * H, S, hd)
-    lser = lse.reshape(B * H, S)
-    dvr = dvec.reshape(B * H, S)
+    lser = lse.reshape(B * H, 1, S)
+    dvr = dvec.reshape(B * H, 1, S)
 
     def q_map(bh, qi, ki):
         return (bh, qi, 0)
@@ -598,9 +595,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         return (b * KV + h // G, ki, 0)
 
     def lse_map(bh, qi, ki):
-        return (bh, qi)
+        return (bh, 0, qi)
 
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal),
@@ -609,14 +606,13 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
                   pl.BlockSpec((1, block_k, hd), kv_map),
                   pl.BlockSpec((1, block_k, hd), kv_map),
                   pl.BlockSpec((1, block_q, hd), q_map),
-                  pl.BlockSpec((1, block_q), lse_map),
-                  pl.BlockSpec((1, block_q), lse_map)],
+                  pl.BlockSpec((1, 1, block_q), lse_map),
+                  pl.BlockSpec((1, 1, block_q), lse_map)],
         out_specs=pl.BlockSpec((1, block_q, hd), q_map),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(qr, kr, vr, dor, lser, dvr)
 
     # dkv: grid over B·KV so head-group grads accumulate in scratch
@@ -635,9 +631,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         kvh = bkv % KV
         g = step // nq
         qi = step % nq
-        return (b * H + kvh * G + g, qi)
+        return (b * H + kvh * G + g, 0, qi)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal, n_q_steps=nq),
@@ -646,17 +642,16 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
                   pl.BlockSpec((1, block_k, hd), kv_map2),
                   pl.BlockSpec((1, block_k, hd), kv_map2),
                   pl.BlockSpec((1, block_q, hd), q_map2),
-                  pl.BlockSpec((1, block_q), lse_map2),
-                  pl.BlockSpec((1, block_q), lse_map2)],
+                  pl.BlockSpec((1, 1, block_q), lse_map2),
+                  pl.BlockSpec((1, 1, block_q), lse_map2)],
         out_specs=[pl.BlockSpec((1, block_k, hd), kv_map2),
                    pl.BlockSpec((1, block_k, hd), kv_map2)],
         out_shape=[jax.ShapeDtypeStruct((B * KV, S, hd), k.dtype),
                    jax.ShapeDtypeStruct((B * KV, S, hd), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(qr, kr, vr, dor, lser, dvr)
 
     return (dq.reshape(B, H, S, hd), dk.reshape(B, KV, S, hd),

@@ -1,9 +1,8 @@
 """jit'd public wrapper for flash attention (fwd + custom-VJP bwd kernels).
 
-On TPU the Pallas kernels run compiled; on CPU (this container) the kernel
-bodies execute under ``interpret=True`` for correctness tests, while model
-code uses the jnp reference (XLA fuses it acceptably on CPU).  Layout
-adapter: models carry (B, S, H, hd); the kernel wants (B, H, S, hd).
+On TPU the Pallas kernels run compiled; lowered for the CPU they execute
+in Pallas interpret mode (``repro.device.pallas_call``).  Layout adapter:
+models carry (B, S, H, hd); the kernel wants (B, H, S, hd).
 """
 from __future__ import annotations
 
@@ -14,12 +13,11 @@ import jax.numpy as jnp
 
 from repro.kernels.flashattn.kernel import (
     flash_attention, flash_attention_bwd, flash_attention_fwd_lse)
-from repro.kernels.flashattn.ref import attention_ref
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attn_diff(q, k, v, causal=True, window=None, block_q=128,
-                    block_k=128, interpret=False):
+                    block_k=128):
     """Differentiable flash attention: fwd AND bwd are Pallas kernels.
 
     q (B,H,S,hd), k/v (B,KV,S,hd) → (B,H,S,hd).  The backward recomputes
@@ -27,62 +25,45 @@ def flash_attn_diff(q, k, v, causal=True, window=None, block_q=128,
     score matrix never exists in HBM in either pass.
     """
     out, _ = flash_attention_fwd_lse(q, k, v, causal=causal, window=window,
-                                     block_q=block_q, block_k=block_k,
-                                     interpret=interpret)
+                                     block_q=block_q, block_k=block_k)
     return out
 
 
-def _fad_fwd(q, k, v, causal, window, block_q, block_k, interpret):
+def _fad_fwd(q, k, v, causal, window, block_q, block_k):
     out, lse = flash_attention_fwd_lse(q, k, v, causal=causal, window=window,
-                                       block_q=block_q, block_k=block_k,
-                                       interpret=interpret)
+                                       block_q=block_q, block_k=block_k)
     return out, (q, k, v, out, lse)
 
 
-def _fad_bwd(causal, window, block_q, block_k, interpret, res, do):
+def _fad_bwd(causal, window, block_q, block_k, res, do):
     q, k, v, out, lse = res
     dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, do, causal=causal,
                                      window=window, block_q=block_q,
-                                     block_k=block_k, interpret=interpret)
+                                     block_k=block_k)
     return dq, dk, dv
 
 
 flash_attn_diff.defvjp(_fad_fwd, _fad_bwd)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def flash_attn(q, k, v, *, causal: bool = True, window: int | None = None,
-               interpret: bool | None = None) -> jax.Array:
+def flash_attn(q, k, v, *, causal: bool = True,
+               window: int | None = None) -> jax.Array:
     """q (B, S, H, hd), k/v (B, S, KV, hd) → (B, S, H, hd)."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    if interpret is None:
-        interpret = not _on_tpu()
-    if _on_tpu() or interpret:
-        if _on_tpu():
-            out = flash_attention(qt, kt, vt, causal=causal, window=window)
-        else:
-            out = flash_attention(qt, kt, vt, causal=causal, window=window,
-                                  interpret=True)
-    else:
-        out = attention_ref(qt, kt, vt, causal=causal, window=window)
+    out = flash_attention(qt, kt, vt, causal=causal, window=window)
     return jnp.swapaxes(out, 1, 2)
 
 
 def flash_attn_model(q, k, v, *, causal=True, window=None,
-                     block_q=128, block_k=128, interpret=None):
+                     block_q=128, block_k=128):
     """Differentiable model-layout wrapper: (B, S, H, hd) in/out, Pallas
-    fwd+bwd kernels underneath (interpret on CPU)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    fwd+bwd kernels underneath."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     S = qt.shape[2]
     bq, bk = min(block_q, S), min(block_k, S)
-    out = flash_attn_diff(qt, kt, vt, causal, window, bq, bk, interpret)
+    out = flash_attn_diff(qt, kt, vt, causal, window, bq, bk)
     return jnp.swapaxes(out, 1, 2)

@@ -5,22 +5,31 @@ array: convolution and re-quantization executing *in parallel on the stream*,
 configured once, driven by runtime parameters (weights, bias, activations,
 requant params).  TPU adaptation:
 
-  * The XPP's 4D-DMA complex addressing → a shifted-window direct convolution:
-    for each (kh, kw) tap, a strided slice of the input tile feeds one int8
-    MXU matmul of shape (OH·OW, Cin) × (Cin, Cout_tile).  No im2col
-    materialization in HBM — the "im2col" happens implicitly in VMEM
-    addressing, the way the RAM-PAEs re-stream the input window.
-  * Zero-point padding: ops.py pads the input with x_zp, so padded taps
+  * The XPP's 4D-DMA complex addressing → a shifted-window direct
+    convolution over a flattened image.  The wrapper splits the zero-point-
+    padded input into its stride phases (phase (p, q) holds pixels
+    (p + sh·r, q + sw·c)), so every tap of a strided conv is a stride-1
+    window of one phase, and flattens each phase to (rows·cols, Cin).  Tap
+    (i, j) is then one contiguous window of that matrix at a static row
+    offset, feeding one int8 MXU matmul (rows, Cin) × (Cin, Cout_tile).
+    Output pixels are computed at every phase column and the wrapper crops
+    the columns past the output width.  No strided value slice and no
+    3-D → 2-D reshape happen in the kernel, and there is no im2col in HBM.
+  * Row tiles: each grid step holds one slab of ``block_rows`` output rows
+    plus the halo its taps reach into (the wrapper materializes the small
+    overlap), so the per-step working set is bounded at any image size.
+  * Zero-point padding: the caller pads the input with x_zp, so padded taps
     contribute exactly zero after the zero-point correction (standard
     integer-conv identity, also what the HPDP bias path folds in).
   * Requantization is fused in the epilogue — int32 accumulator never leaves
     VMEM (the paper: "these two operations process the data stream in
     parallel, ensuring continuous execution without introducing additional
     delays").
-  * Grid: (batch, Cout tiles).  One (padded) input image and one Cout tile of
-    weights resident in VMEM per step.  Paper-scale layers (194×194×24 int8 ≈
-    0.9 MiB) fit trivially; ops.py asserts the VMEM budget and row-tiles the
-    image when larger.
+  * ABFT: the checksum variant appends the four int8 limbs of the Cout-
+    summed check filter (``abft.int8_limbs``) as four extra output channels
+    of the same MXU matmul — the Huang–Abraham column-checksum matrix.  The
+    check accumulates in its own columns, independent of the Cout columns
+    it verifies.
 
 Taps (KH·KW) are unrolled in Python — static 1–9 iterations for the paper's
 1×1/3×3 layers, each a dense MXU call.
@@ -34,96 +43,118 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.core import abft
+from repro.device import pallas_call
+
+_PARALLEL3 = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"))
 
 
-def _qconv2d_kernel(x_ref, w_ref, colsum_ref, bias_ref, scale_ref, zps_ref,
-                    out_ref, *, stride, oh, ow):
-    kh, kw, cin, _ = w_ref.shape
-    x = x_ref[0]                      # (Hp, Wp, Cin) int8
-    acc = _tap_acc(x, w_ref, oh, ow, stride, cin, out_ref.shape[-1])
-    x_zp = zps_ref[0]
-    out_zp = zps_ref[1]
-    acc = acc - x_zp * colsum_ref[...][None, :] + bias_ref[...][None, :]
-    y = acc.astype(jnp.float32) * scale_ref[...][None, :]
-    y = jnp.round(y) + out_zp.astype(jnp.float32)
-    out_ref[0] = jnp.clip(y, -128.0, 127.0).astype(jnp.int8).reshape(
-        oh, ow, out_ref.shape[-1])
-
-
-def _tap_acc(x, w_ref, oh, ow, stride, cin, cout, dtype=None):
-    """Shifted-window tap loop: the shared direct-conv inner pattern."""
+def _tap_acc(x_ref, w_ref, *, stride, wph, rows):
+    """Shifted-window tap loop over the flattened stride phases."""
     sh, sw = stride
     kh, kw = w_ref.shape[0], w_ref.shape[1]
-    acc = jnp.zeros((oh * ow, cout), jnp.int32)
+    acc = None
     for i in range(kh):
         for j in range(kw):
-            patch = jax.lax.slice(
-                x, (i, j, 0), (i + (oh - 1) * sh + 1, j + (ow - 1) * sw + 1, cin),
-                (sh, sw, 1),
-            )
-            lhs = patch.reshape(oh * ow, cin)
-            rhs = w_ref[i, j]
-            if dtype is not None:
-                lhs = lhs.astype(dtype)
-            acc += jax.lax.dot_general(
-                lhs, rhs,
+            phase = (i % sh) * sw + j % sw
+            off = (i // sh) * wph + j // sw
+            part = jax.lax.dot_general(
+                x_ref[0, 0, phase, pl.ds(off, rows), :], w_ref[i, j],
                 dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
+                preferred_element_type=jnp.int32)
+            acc = part if acc is None else acc + part
     return acc
 
 
 def _qconv2d_acc_kernel(x_ref, w_ref, colsum_ref, zp_ref, out_ref,
-                        *, stride, oh, ow):
-    kh, kw, cin, _ = w_ref.shape
-    cout = out_ref.shape[-1]
-    x = x_ref[0]                      # (Hp, Wp, Cin) int8, zp-padded
-    acc = _tap_acc(x, w_ref, oh, ow, stride, cin, cout)
+                        *, stride, wph, rows):
+    acc = _tap_acc(x_ref, w_ref, stride=stride, wph=wph, rows=rows)
     # conv(x_p - zp, w) == conv(x_p, w) - zp * sum(w): every output pixel
     # covers all kh·kw·cin taps because x is pre-padded with the zero point
-    acc = acc - zp_ref[0] * colsum_ref[...][None, :]
-    out_ref[0] = acc.reshape(oh, ow, cout)
+    out_ref[0, 0] = acc - zp_ref[0] * colsum_ref[...]
 
 
-def _qconv2d_acc_checksum_kernel(x_ref, w_ref, colsum_ref, wcheck_ref,
-                                 zp_ref, out_ref, check_ref, *, stride, oh, ow):
-    """Accumulator kernel with the ABFT check channel fused in: one extra
-    Cout=1 tap matvec per step emits want = conv(x - zp, w_check) as a
-    second output, so per-pixel detection needs no separate conv pass."""
-    c = pl.program_id(1)
-    kh, kw, cin, _ = w_ref.shape
-    cout = out_ref.shape[-1]
-    x = x_ref[0]
-    acc = _tap_acc(x, w_ref, oh, ow, stride, cin, cout)
-    acc = acc - zp_ref[0] * colsum_ref[...][None, :]
-    out_ref[0] = acc.reshape(oh, ow, cout)
-
-    # the check channel is Cout-block-independent: emit it once per image
-    @pl.when(c == 0)
-    def _check():
-        want = _tap_acc(x, wcheck_ref, oh, ow, stride, cin, 1,
-                        dtype=jnp.int32)
-        # conv(x_p - zp, w_check) == conv(x_p, w_check) - zp * sum(w_check);
-        # w_check is fully resident, so its tap sum is computed in-kernel
-        want = want - zp_ref[0] * jnp.sum(wcheck_ref[...])
-        check_ref[0] = want.reshape(oh, ow)
+def _qconv2d_kernel(x_ref, w_ref, colsum_ref, bias_ref, scale_ref, zps_ref,
+                    out_ref, *, stride, wph, rows):
+    acc = _tap_acc(x_ref, w_ref, stride=stride, wph=wph, rows=rows)
+    x_zp = zps_ref[0]
+    out_zp = zps_ref[1]
+    acc = acc - x_zp * colsum_ref[...] + bias_ref[...]
+    y = acc.astype(jnp.float32) * scale_ref[...]
+    y = jnp.round(y) + out_zp.astype(jnp.float32)
+    out_ref[0, 0] = jnp.clip(y, -128.0, 127.0).astype(jnp.int8)
 
 
-def _conv_geometry(x_q, w_q, stride, block_cout):
-    n, hp, wp, cin = x_q.shape
-    kh, kw, cin2, cout = w_q.shape
-    assert cin == cin2, (x_q.shape, w_q.shape)
+def _fit(a, length, axis):
+    """Crop or zero-pad ``a`` along ``axis`` to exactly ``length``."""
+    a = jax.lax.slice_in_dim(a, 0, min(length, a.shape[axis]), axis=axis)
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, length - a.shape[axis])
+    return jnp.pad(a, pad)
+
+
+def _conv_slabs(xp, kh, kw, stride, block_rows):
+    """Phase-split, flatten and row-tile the zp-padded input.
+
+    Returns (slabs, geometry): slabs (N, T, sh·sw, rows + halo, Cin), where
+    tile t holds flattened phase rows [t·rows, (t+1)·rows + halo)."""
+    n, hp, wp, cin = xp.shape
     sh, sw = stride
     oh = (hp - kh) // sh + 1
     ow = (wp - kw) // sw + 1
+    hph, wph = -(-hp // sh), -(-wp // sw)
+    # cells past the image only ever feed cropped output columns/rows
+    xp = _fit(_fit(xp, hph * sh, 1), wph * sw, 2)
+    phases = xp.reshape(n, hph, sh, wph, sw, cin).transpose(0, 2, 4, 1, 3, 5)
+    flat = phases.reshape(n, sh * sw, hph * wph, cin)
+    th = max(1, min(oh, block_rows // wph))
+    t = -(-oh // th)
+    rows = th * wph
+    halo = ((kh - 1) // sh) * wph + (kw - 1) // sw
+    extra = -(-halo // rows)
+    flat = _fit(flat, (t + extra) * rows, 2)
+    shifted = [flat[:, :, s * rows:(s + t) * rows].reshape(
+        n, sh * sw, t, rows, cin) for s in range(extra + 1)]
+    slabs = jnp.concatenate(shifted, axis=3)[:, :, :, :rows + halo]
+    return slabs.transpose(0, 2, 1, 3, 4), (oh, ow, wph, th, t, rows)
+
+
+def _conv_call(kernel, xp, w_q, operands, operand_specs, out_dtype, *,
+               stride, block_cout, block_rows):
+    """Run one conv kernel over the slabs; crop back to (N, OH, OW, Cout)."""
+    n = xp.shape[0]
+    kh, kw, cin, cout = w_q.shape
+    assert xp.shape[3] == cin, (xp.shape, w_q.shape)
+    slabs, (oh, ow, wph, th, t, rows) = _conv_slabs(xp, kh, kw, stride,
+                                                     block_rows)
     block_cout = min(block_cout, cout)
-    return n, hp, wp, cin, kh, kw, cout, oh, ow, block_cout
+    out = pallas_call(
+        functools.partial(kernel, stride=stride, wph=wph, rows=rows),
+        grid=(n, t, pl.cdiv(cout, block_cout)),
+        in_specs=[
+            pl.BlockSpec((1, 1) + slabs.shape[2:],
+                         lambda b, r, c: (b, r, 0, 0, 0)),
+            pl.BlockSpec((kh, kw, cin, block_cout),
+                         lambda b, r, c: (0, 0, 0, c)),
+        ] + operand_specs(block_cout),
+        out_specs=pl.BlockSpec((1, 1, rows, block_cout),
+                               lambda b, r, c: (b, r, 0, c)),
+        out_shape=jax.ShapeDtypeStruct((n, t, rows, cout), out_dtype),
+        compiler_params=_PARALLEL3,
+    )(slabs, w_q, *operands)
+    return out.reshape(n, t * th, wph, cout)[:, :oh, :ow, :]
+
+
+def _row_spec(block_cout):
+    return pl.BlockSpec((1, block_cout), lambda b, r, c: (0, c))
+
+
+_SCALARS = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("stride", "block_cout", "interpret")
+    jax.jit, static_argnames=("stride", "block_cout", "block_rows")
 )
 def qconv2d_acc(
     x_q: jax.Array,          # (N, Hp, Wp, Cin) int8 — already zp-padded
@@ -133,32 +164,20 @@ def qconv2d_acc(
     *,
     stride: tuple = (1, 1),
     block_cout: int = 128,
-    interpret: bool = False,
+    block_rows: int = 2048,
 ) -> jax.Array:
-    """Raw int32 conv accumulator conv(x - zp, w) — backend-registry entry."""
-    n, hp, wp, cin, kh, kw, cout, oh, ow, block_cout = _conv_geometry(
-        x_q, w_q, stride, block_cout)
-    kernel = functools.partial(_qconv2d_acc_kernel, stride=stride, oh=oh, ow=ow)
-    return pl.pallas_call(
-        kernel,
-        grid=(n, pl.cdiv(cout, block_cout)),
-        in_specs=[
-            pl.BlockSpec((1, hp, wp, cin), lambda b, c: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, block_cout), lambda b, c: (0, 0, 0, c)),
-            pl.BlockSpec((block_cout,), lambda b, c: (c,)),
-            pl.BlockSpec((1,), lambda b, c: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, oh, ow, block_cout), lambda b, c: (b, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n, oh, ow, cout), jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(x_q, w_q, colsum, zp)
+    """Raw int32 conv accumulator conv(x - zp, w) — backend-registry entry.
+
+    ``block_rows`` bounds the flattened output rows (output rows × phase
+    width) one grid step computes."""
+    return _conv_call(
+        _qconv2d_acc_kernel, x_q, w_q, (colsum.reshape(1, -1), zp),
+        lambda bc: [_row_spec(bc), _SCALARS], jnp.int32,
+        stride=stride, block_cout=block_cout, block_rows=block_rows)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("stride", "block_cout", "interpret")
+    jax.jit, static_argnames=("stride", "block_cout", "block_rows")
 )
 def qconv2d_acc_checksum(
     x_q: jax.Array,          # (N, Hp, Wp, Cin) int8 — already zp-padded
@@ -169,44 +188,25 @@ def qconv2d_acc_checksum(
     *,
     stride: tuple = (1, 1),
     block_cout: int = 128,
-    interpret: bool = False,
+    block_rows: int = 2048,
 ):
     """(acc, want): conv accumulator plus the fused per-pixel ABFT channel.
 
     want (N, OH, OW) i32 equals the Cout-sum of acc mod 2^32 on a fault-free
-    pass; see core/abft.abft_qconv2d."""
-    n, hp, wp, cin, kh, kw, cout, oh, ow, block_cout = _conv_geometry(
-        x_q, w_q, stride, block_cout)
-    kernel = functools.partial(_qconv2d_acc_checksum_kernel, stride=stride,
-                               oh=oh, ow=ow)
-    return pl.pallas_call(
-        kernel,
-        grid=(n, pl.cdiv(cout, block_cout)),
-        in_specs=[
-            pl.BlockSpec((1, hp, wp, cin), lambda b, c: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, block_cout), lambda b, c: (0, 0, 0, c)),
-            pl.BlockSpec((block_cout,), lambda b, c: (c,)),
-            pl.BlockSpec((kh, kw, cin, 1), lambda b, c: (0, 0, 0, 0)),
-            pl.BlockSpec((1,), lambda b, c: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, oh, ow, block_cout), lambda b, c: (b, 0, 0, c)),
-            # revisited across cout blocks → c must be "arbitrary" below
-            pl.BlockSpec((1, oh, ow), lambda b, c: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, oh, ow, cout), jnp.int32),
-            jax.ShapeDtypeStruct((n, oh, ow), jnp.int32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(x_q, w_q, colsum, w_check, zp)
+    pass; see core/abft.abft_qconv2d.  The check filter's int8 limbs ride as
+    four extra output channels of the one kernel call."""
+    cout = w_q.shape[3]
+    limbs = abft.int8_limbs(w_check[..., 0])              # (KH, KW, Cin, 4)
+    w_ext = jnp.concatenate([w_q, limbs], axis=3)
+    colsum_ext = jnp.concatenate(
+        [colsum, jnp.sum(limbs.astype(jnp.int32), axis=(0, 1, 2))])
+    acc = qconv2d_acc(x_q, w_ext, colsum_ext, zp, stride=stride,
+                      block_cout=block_cout, block_rows=block_rows)
+    return acc[..., :cout], abft.from_limbs(acc[..., cout:])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("stride", "block_cout", "interpret")
+    jax.jit, static_argnames=("stride", "block_cout", "block_rows")
 )
 def qconv2d(
     x_q: jax.Array,          # (N, Hp, Wp, Cin) int8 — already zp-padded
@@ -218,33 +218,11 @@ def qconv2d(
     *,
     stride: tuple = (1, 1),
     block_cout: int = 128,
-    interpret: bool = False,
+    block_rows: int = 2048,
 ) -> jax.Array:
-    n, hp, wp, cin = x_q.shape
-    kh, kw, cin2, cout = w_q.shape
-    assert cin == cin2, (x_q.shape, w_q.shape)
-    sh, sw = stride
-    oh = (hp - kh) // sh + 1
-    ow = (wp - kw) // sw + 1
-    block_cout = min(block_cout, cout)
-    grid = (n, pl.cdiv(cout, block_cout))
-
-    kernel = functools.partial(_qconv2d_kernel, stride=stride, oh=oh, ow=ow)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, hp, wp, cin), lambda b, c: (b, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, cin, block_cout), lambda b, c: (0, 0, 0, c)),
-            pl.BlockSpec((block_cout,), lambda b, c: (c,)),
-            pl.BlockSpec((block_cout,), lambda b, c: (c,)),
-            pl.BlockSpec((block_cout,), lambda b, c: (c,)),
-            pl.BlockSpec((2,), lambda b, c: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, oh, ow, block_cout), lambda b, c: (b, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n, oh, ow, cout), jnp.int8),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(x_q, w_q, colsum, bias, scale, zps)
+    return _conv_call(
+        _qconv2d_kernel, x_q, w_q,
+        (colsum.reshape(1, -1), bias.reshape(1, -1), scale.reshape(1, -1),
+         zps),
+        lambda bc: [_row_spec(bc)] * 3 + [_SCALARS], jnp.int8,
+        stride=stride, block_cout=block_cout, block_rows=block_rows)

@@ -1,8 +1,7 @@
 """jit'd public wrapper for the qconv2d Pallas kernel.
 
-Handles zero-point padding, parameter bundle preparation, kernel-vs-ref
-dispatch, and falls back to the jnp reference when the image does not fit the
-whole-image VMEM strategy (not the case for any paper workload).
+Handles zero-point padding, parameter bundle preparation and kernel-vs-ref
+dispatch.  The kernel row-tiles the image, so every size runs on it.
 """
 from __future__ import annotations
 
@@ -15,14 +14,6 @@ import jax.numpy as jnp
 from repro.core import quant
 from repro.kernels.qconv2d.kernel import qconv2d as qconv2d_pallas
 from repro.kernels.qconv2d.ref import qconv2d_ref
-
-# Whole-image VMEM strategy budget (int8 bytes): input + weights + acc must
-# sit in ~16 MiB VMEM; stay conservative.
-_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 class QConvParams(NamedTuple):
@@ -50,12 +41,12 @@ def _same_pads(h: int, w: int, kh: int, kw: int, sh: int, sw: int):
     return ((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2))
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "padding", "use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("stride", "padding", "use_kernel"))
 def qconv2d_op(
     x_q: jax.Array, x_zp: jax.Array, w_q: jax.Array, colsum: jax.Array,
     bias_i32: jax.Array, scale: jax.Array, out_zp: jax.Array,
     *, stride: Tuple[int, int] = (1, 1), padding: str = "SAME",
-    use_kernel: bool = True, interpret: bool = False,
+    use_kernel: bool = True,
 ) -> jax.Array:
     """int8 NHWC in → int8 NHWC out quantized conv+requant."""
     n, h, w, cin = x_q.shape
@@ -68,9 +59,7 @@ def qconv2d_op(
     else:
         pads = tuple(padding)
 
-    fits = (h + sum(pads[0])) * (w + sum(pads[1])) * cin + kh * kw * cin * min(cout, 128) \
-        <= _VMEM_BUDGET_BYTES
-    if use_kernel and fits:
+    if use_kernel:
         # zero-point padding: padded taps contribute (x_zp - x_zp)·w == 0,
         # i.e. padding with the zp value is exactly "pad with real 0.0"
         xp = jax.lax.pad(
@@ -82,8 +71,7 @@ def qconv2d_op(
         )
         zps = jnp.stack([x_zp.astype(jnp.int32), out_zp.astype(jnp.int32)])
         return qconv2d_pallas(xp, w_q, colsum, bias_i32, scale, zps,
-                              stride=stride,
-                              interpret=interpret or not _on_tpu())
+                              stride=stride)
     return qconv2d_ref(x_q, x_zp, w_q, bias_i32, scale, out_zp,
                        stride=stride, padding=pads if padding not in ("SAME", "VALID") else padding)
 
@@ -94,7 +82,7 @@ def qconv_act(
     x_scale: jax.Array, x_zp: jax.Array,
     out_scale: jax.Array, out_zp: jax.Array,
     *, stride: Tuple[int, int] = (1, 1), padding: str = "SAME",
-    use_kernel: bool = False, interpret: bool = False,
+    use_kernel: bool = False,
 ) -> jax.Array:
     """float → int8 conv+requant → float, integer arithmetic in between."""
     x_q = quant.quantize(x, x_scale, x_zp)
@@ -102,5 +90,5 @@ def qconv_act(
     rq_scale = quant.requant_scale(x_scale, params.w_scale, out_scale)
     y_q = qconv2d_op(x_q, x_zp, params.w_q, params.colsum, bias_i32, rq_scale,
                      out_zp, stride=stride, padding=padding,
-                     use_kernel=use_kernel, interpret=interpret)
+                     use_kernel=use_kernel)
     return (y_q.astype(jnp.float32) - out_zp.astype(jnp.float32)) * out_scale

@@ -30,8 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.core import abft
+from repro.device import pallas_call
 
 
 def _qmatmul_kernel(x_ref, w_ref, colsum_ref, bias_ref, scale_ref, zps_ref,
@@ -57,8 +57,8 @@ def _qmatmul_kernel(x_ref, w_ref, colsum_ref, bias_ref, scale_ref, zps_ref,
         x_zp = zps_ref[0]
         out_zp = zps_ref[1]
         acc = acc_ref[...]
-        acc = acc - x_zp * colsum_ref[...][None, :] + bias_ref[...][None, :]
-        y = acc.astype(jnp.float32) * scale_ref[...][None, :]
+        acc = acc - x_zp * colsum_ref[...] + bias_ref[...]
+        y = acc.astype(jnp.float32) * scale_ref[...]
         y = jnp.round(y) + out_zp.astype(jnp.float32)
         out_ref[...] = jnp.clip(y, -128.0, 127.0).astype(jnp.int8)
 
@@ -88,13 +88,14 @@ def _qmatmul_acc_kernel(x_ref, w_ref, out_ref, *, k_total: int):
     )
 
 
-def _qmatmul_acc_checksum_kernel(x_ref, w_ref, wcheck_ref, out_ref, check_ref,
+def _qmatmul_acc_checksum_kernel(x_ref, w_ref, limbs_ref, out_ref, check_ref,
                                  *, k_total: int):
     """Accumulator kernel with the ABFT check vector fused in: alongside each
-    (block_m, block_k) × (block_k, block_n) MXU step, one extra block-row
-    matvec accumulates want = X · w_check into a second output — detection
-    costs ~1/block_n extra work inside the kernel instead of a separate
-    matvec pass over X."""
+    (block_m, block_k) × (block_k, block_n) MXU step, one extra (block_k, 4)
+    int8 MXU product accumulates X · limbs(w_check) into a second output —
+    the four int8 limbs of the int32 check vector (``abft.int8_limbs``), as
+    the MXU takes no int32 operand.  Detection costs ~4/block_n extra work
+    inside the kernel instead of a separate matvec pass over X."""
     n = pl.program_id(1)
     k = pl.program_id(2)
 
@@ -116,9 +117,9 @@ def _qmatmul_acc_checksum_kernel(x_ref, w_ref, wcheck_ref, out_ref, check_ref,
     # the check column is N-independent: accumulate it once per (m, k) tile
     @pl.when(n == 0)
     def _check():
-        wc = _mask_k_tail(wcheck_ref[...], k, k_total)
+        limbs = _mask_k_tail(limbs_ref[...], k, k_total)
         check_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(jnp.int32), wc,
+            x_ref[...], limbs,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
@@ -133,7 +134,7 @@ def _acc_grid(M, N, K, block_m, block_n, block_k):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret")
+    jax.jit, static_argnames=("block_m", "block_n", "block_k")
 )
 def qmatmul_acc(
     x_q: jax.Array,          # (M, K) int8
@@ -142,7 +143,6 @@ def qmatmul_acc(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = False,
 ) -> jax.Array:
     """Raw int32 accumulator X·W — the backend-registry entry point.
 
@@ -154,7 +154,7 @@ def qmatmul_acc(
     assert K == K2, (x_q.shape, w_q.shape)
     grid, block_m, block_n, block_k = _acc_grid(M, N, K, block_m, block_n,
                                                 block_k)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_qmatmul_acc_kernel, k_total=K),
         grid=grid,
         in_specs=[
@@ -163,15 +163,14 @@ def qmatmul_acc(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
     )(x_q, w_q)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret")
+    jax.jit, static_argnames=("block_m", "block_n", "block_k")
 )
 def qmatmul_acc_checksum(
     x_q: jax.Array,          # (M, K) int8
@@ -181,7 +180,6 @@ def qmatmul_acc_checksum(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = False,
 ):
     """(acc, want): accumulator plus the fused ABFT check vector.
 
@@ -193,33 +191,32 @@ def qmatmul_acc_checksum(
     assert K == K2, (x_q.shape, w_q.shape)
     grid, block_m, block_n, block_k = _acc_grid(M, N, K, block_m, block_n,
                                                 block_k)
-    acc, want = pl.pallas_call(
+    acc, parts = pallas_call(
         functools.partial(_qmatmul_acc_checksum_kernel, k_total=K),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda m, n, k: (m, k)),
             pl.BlockSpec((block_k, block_n), lambda m, n, k: (k, n)),
-            pl.BlockSpec((block_k, 1), lambda m, n, k: (k, 0)),
+            pl.BlockSpec((block_k, 4), lambda m, n, k: (k, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_m, block_n), lambda m, n, k: (m, n)),
             # revisited across n and k → n must be "arbitrary" below
-            pl.BlockSpec((block_m, 1), lambda m, n, k: (m, 0)),
+            pl.BlockSpec((block_m, 4), lambda m, n, k: (m, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), jnp.int32),
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
+            jax.ShapeDtypeStruct((M, 4), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
-        interpret=interpret,
-    )(x_q, w_q, w_check.reshape(-1, 1))
-    return acc, want[:, 0]
+    )(x_q, w_q, abft.int8_limbs(w_check))
+    return acc, abft.from_limbs(parts)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "block_k", "interpret")
+    jax.jit, static_argnames=("block_m", "block_n", "block_k")
 )
 def qmatmul(
     x_q: jax.Array,          # (M, K) int8
@@ -232,7 +229,6 @@ def qmatmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = False,
 ) -> jax.Array:
     M, K = x_q.shape
     K2, N = w_q.shape
@@ -243,22 +239,22 @@ def qmatmul(
     block_k = min(block_k, K)
     grid = (pl.cdiv(M, block_m), pl.cdiv(N, block_n), pl.cdiv(K, block_k))
 
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_qmatmul_kernel, k_total=K),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda m, n, k: (m, k)),
             pl.BlockSpec((block_k, block_n), lambda m, n, k: (k, n)),
-            pl.BlockSpec((block_n,), lambda m, n, k: (n,)),
-            pl.BlockSpec((block_n,), lambda m, n, k: (n,)),
-            pl.BlockSpec((block_n,), lambda m, n, k: (n,)),
-            pl.BlockSpec((2,), lambda m, n, k: (0,)),
+            pl.BlockSpec((1, block_n), lambda m, n, k: (0, n)),
+            pl.BlockSpec((1, block_n), lambda m, n, k: (0, n)),
+            pl.BlockSpec((1, block_n), lambda m, n, k: (0, n)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
-    )(x_q, w_q, colsum, bias, scale, zps)
+    )(x_q, w_q, colsum.reshape(1, -1), bias.reshape(1, -1),
+      scale.reshape(1, -1), zps)

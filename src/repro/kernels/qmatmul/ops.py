@@ -5,10 +5,10 @@ float activation + pre-quantized weight bundle and produces a float
 activation, running the hot matmul entirely in int8/int32 (the paper's
 technique), with requantization fused.
 
-The kernel runs natively on TPU; on hosts without TPU (this container) it
-executes under ``interpret=True``, which is the same "cycle-level simulator
-stands in for hardware" methodology the paper uses (XDBG / HPDP simulator vs
-the flight unit).
+The kernel runs compiled on the TPU; lowered for the CPU it executes in
+Pallas interpret mode (``repro.device.pallas_call``), the same "cycle-level
+simulator stands in for hardware" methodology the paper uses (XDBG / HPDP
+simulator vs the flight unit).
 """
 from __future__ import annotations
 
@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from repro.core import quant
 from repro.kernels.qmatmul.kernel import qmatmul as qmatmul_pallas
 from repro.kernels.qmatmul.ref import qmatmul_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 class QLinearParams(NamedTuple):
@@ -45,17 +41,16 @@ def make_qlinear_params(w: jax.Array, bias: jax.Array | None = None) -> QLinearP
     return QLinearParams(qt.q, qt.scale, colsum, bias.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def qmatmul_op(
     x_q: jax.Array, x_zp: jax.Array, w_q: jax.Array, colsum: jax.Array,
     bias_i32: jax.Array, scale: jax.Array, out_zp: jax.Array,
-    *, use_kernel: bool = True, interpret: bool = False,
+    *, use_kernel: bool = True,
 ) -> jax.Array:
     """int8 in → int8 out quantized matmul. Dispatches kernel vs jnp ref."""
     if use_kernel:
         zps = jnp.stack([x_zp.astype(jnp.int32), out_zp.astype(jnp.int32)])
-        return qmatmul_pallas(x_q, w_q, colsum, bias_i32, scale, zps,
-                              interpret=interpret or not _on_tpu())
+        return qmatmul_pallas(x_q, w_q, colsum, bias_i32, scale, zps)
     return qmatmul_ref(x_q, x_zp, w_q, bias_i32, scale, out_zp)
 
 
@@ -64,7 +59,7 @@ def qlinear_act(
     params: QLinearParams,
     x_scale: jax.Array, x_zp: jax.Array,       # calibrated input qparams
     out_scale: jax.Array, out_zp: jax.Array,   # calibrated output qparams
-    *, use_kernel: bool = False, interpret: bool = False,
+    *, use_kernel: bool = False,
 ) -> jax.Array:
     """float → [quantize] → int8 matmul+requant → [dequantize] → float.
 
@@ -78,7 +73,7 @@ def qlinear_act(
     bias_i32 = jnp.round(params.bias_f / (x_scale * params.w_scale)).astype(jnp.int32)
     rq_scale = quant.requant_scale(x_scale, params.w_scale, out_scale)
     y_q = qmatmul_op(x_q, x_zp, params.w_q, params.colsum, bias_i32, rq_scale,
-                     out_zp, use_kernel=use_kernel, interpret=interpret)
+                     out_zp, use_kernel=use_kernel)
     y = (y_q.astype(jnp.float32) - out_zp.astype(jnp.float32)) * out_scale
     return y.reshape(*lead, -1)
 

@@ -20,14 +20,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
-    """Arbitrary mesh (tests / reduced dry-runs)."""
+    """Arbitrary mesh (tests / reduced dry-runs).
+
+    Axes are ``Auto``: the sharding rules here are hints the compiler
+    propagates (``with_sharding_constraint``), not the explicit-sharding
+    types ``jax.make_mesh`` defaults to."""
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):]
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

@@ -15,6 +15,7 @@ import time
 
 import jax
 
+from repro import device
 from repro.configs import registry
 from repro.models import api as model_api
 from repro.models.config import reduced
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--fault-drill", action="store_true",
                     help="inject an SEU mid-serve and prove recovery")
     args = ap.parse_args()
+    device.enable_compile_cache()
 
     cfg = registry.get(args.arch)
     if args.reduced:

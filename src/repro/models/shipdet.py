@@ -112,8 +112,7 @@ def golden_weights(params: List[Dict[str, Any]]) -> List[jax.Array]:
 
 def forward(specs: List[ConvSpec], params: List[Dict[str, Any]], x: jax.Array,
             *, policy: Policy = Policy.NONE, policy_map=None,
-            use_kernel: bool = False,
-            interpret: bool = False, inject=None, inject_layer=None,
+            use_kernel: bool = False, inject=None, inject_layer=None,
             backend=None, w_checks: Optional[List[jax.Array]] = None,
             golden_wq: Optional[List[jax.Array]] = None
             ) -> Tuple[jax.Array, Dict]:
@@ -195,14 +194,14 @@ def forward(specs: List[ConvSpec], params: List[Dict[str, Any]], x: jax.Array,
             x = qconv_ops.qconv_act(
                 x, p["qconv"], p["in_scale"], p["in_zp"],
                 p["out_scale"], p["out_zp"], stride=stride, padding="SAME",
-                use_kernel=use_kernel, interpret=interpret)
+                use_kernel=use_kernel)
         if i < len(specs) - 1:
             x = jax.nn.relu(x)
     return x, stats
 
 
 def layer_forward(s: ConvSpec, p: Dict[str, Any], x: jax.Array,
-                  quantized: bool = True, interpret: bool = True) -> jax.Array:
+                  quantized: bool = True) -> jax.Array:
     """One layer, float in → float out; quantized=False is the float oracle
     (dequantized weights, float conv) used by the Fig.-4-style validation."""
     stride = (s.stride, s.stride)
@@ -210,7 +209,7 @@ def layer_forward(s: ConvSpec, p: Dict[str, Any], x: jax.Array,
         return qconv_ops.qconv_act(
             x, p["qconv"], p["in_scale"], p["in_zp"],
             p["out_scale"], p["out_zp"], stride=stride, padding="SAME",
-            use_kernel=True, interpret=interpret)
+            use_kernel=True)
     w = p["qconv"].w_q.astype(jnp.float32) * p["qconv"].w_scale
     y = jax.lax.conv_general_dilated(
         x, w, stride, "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))

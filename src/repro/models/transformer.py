@@ -30,6 +30,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common
@@ -147,16 +148,25 @@ def _qdot(cfg: ArchConfig, x, bp, name):
     With ``cfg.policy_map`` set, the site ``ffn.<name>`` resolves to a
     dependability policy (and optionally a backend) and the accumulator
     runs through ``dependable_matmul_acc`` — selective hardening of the
-    FFN hot path.  Clean-path outputs stay bit-identical to the unmapped
-    dispatch for every policy (exact integer checks never fire); the scan
-    over layers means the assignment is per-matmul-name, uniform across
-    the layer stack (see core/policy_map.py)."""
+    FFN hot path.  Its integer accumulators equal the unmapped dispatch's
+    for every policy (exact integer checks never fire).  On the CPU the
+    outputs are bit-identical too; on the TPU the map's extra integer ops
+    change how XLA fuses the bf16 ops around them, and XLA's excess bf16
+    precision inside a fusion can then move the last bits.  The scan over
+    layers means the assignment is per-matmul-name, uniform across the
+    layer stack (see core/policy_map.py)."""
     if name + "_q" in bp:
         from repro.kernels import dispatch
         x_q, x_s = _quantize_act(x)
         w_q = bp[name + "_q"]
         lead = x_q.shape[:-1]
-        x2 = x_q.reshape(-1, x_q.shape[-1])
+        # Row-major, as a Pallas kernel takes it.  Left free, XLA lays the
+        # operand of its own int8 dot out differently and propagates that
+        # layout into the neighbouring float matmuls, whose accumulation
+        # order then changes: on the TPU the jnp and pallas backends would
+        # release different tokens from identical integer accumulators.
+        x2 = with_layout_constraint(x_q.reshape(-1, x_q.shape[-1]),
+                                    Layout(major_to_minor=(0, 1)))
         if cfg.policy_map is not None:
             from repro.core import dependability as dep
             pol, pm_backend = cfg.policy_map.resolve("ffn." + name)
@@ -320,7 +330,7 @@ def _attention_core(cfg: ArchConfig, q, k, v, positions, ctx):
     if ctx is None:
         return flash_attn_model(q, k, v, window=cfg.swa_window)
 
-    from repro.compat import shard_map
+    from jax import shard_map
     H, KV = cfg.n_heads, cfg.n_kv_heads
     msize = ctx.model_size
     tp_ok = (ctx.model not in ctx.dp and H % msize == 0 and KV % msize == 0)
@@ -490,7 +500,7 @@ def moe_mode(cfg: ArchConfig, model_size: int) -> str:
 
 def _moe_ffn(cfg: ArchConfig, bp, x, ctx: ShardCtx):
     """shard_map wrapper: explicit EP (or expert-TP) + FSDP for the experts."""
-    from repro.compat import shard_map
+    from jax import shard_map
     m = cfg.moe
     dp = ctx.dp
     mode = moe_mode(cfg, ctx.model_size)

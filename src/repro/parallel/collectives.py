@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 
 def ring_all_gather(x: jax.Array, axis_name: str, axis: int = 0) -> jax.Array:
@@ -24,7 +23,7 @@ def ring_all_gather(x: jax.Array, axis_name: str, axis: int = 0) -> jax.Array:
     a consumer can compute on shard k while shard k+1 is in flight — the
     collective-overlap hillclimb lever.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     chunks = [x]

@@ -508,8 +508,9 @@ class DecodeStage(Stage):
 
     def reset_state(self):
         ex = self.ex
-        self.cache = model_api.init_cache(ex.cfg, ex.capacity, ex.max_len)
-        self.tokens = jnp.zeros((ex.capacity,), jnp.int32)
+        self.cache = ex.place(
+            model_api.init_cache(ex.cfg, ex.capacity, ex.max_len))
+        self.tokens = ex.place(jnp.zeros((ex.capacity,), jnp.int32))
         self.slot_pos = np.zeros(ex.capacity, np.int32)
         self.slot_remaining = np.zeros(ex.capacity, np.int32)
         self.active: dict = {}                    # slot -> Request
@@ -746,8 +747,12 @@ class StreamingExecutor:
                  storage_scrub: str = "off", storage_scrub_every: int = 1,
                  certify: Optional[Callable[[Request], bool]] = None,
                  drain_barrier: bool = False, multi_step: int = 1,
-                 tracer=None, event_log=None, metrics=None):
+                 tracer=None, event_log=None, metrics=None, device=None):
         self.cfg = cfg
+        # the device that holds this executor's params and decode state
+        # (a fleet puts each replica on its own chip); None leaves placement
+        # to JAX's default device
+        self.device = device
         self.params = params
         self.capacity = capacity
         self.max_len = max_len
@@ -868,6 +873,19 @@ class StreamingExecutor:
         """The jitted (decode, prefill) pair, shareable with same-config
         executors via the ``compiled=`` constructor argument."""
         return (self._decode, self._prefill)
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, value):
+        self._params = self.place(value)
+
+    def place(self, tree):
+        """Commit ``tree`` to this executor's device (as is when unset)."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
 
     def reset(self, params=None):
         """Return run state (channels, slots, cache, per-run stats) to

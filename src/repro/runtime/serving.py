@@ -55,7 +55,7 @@ class Engine:
                  storage_scrub_every: Optional[int] = None,
                  certify: Optional[Callable[[Request], bool]] = None,
                  drain_barrier: bool = False, multi_step: int = 1,
-                 tracer=None, event_log=None, metrics=None):
+                 tracer=None, event_log=None, metrics=None, device=None):
         # engine-level execution-backend override for the quantized hot
         # paths (core/backend registry); baked into cfg so the jitted
         # decode/prefill pair and any compiled-pair sharing stay consistent
@@ -93,7 +93,7 @@ class Engine:
             storage_scrub_every=storage_scrub_every,
             certify=certify, drain_barrier=drain_barrier,
             multi_step=multi_step, tracer=tracer, event_log=event_log,
-            metrics=metrics)
+            metrics=metrics, device=device)
 
     # ------------------------------------------------------------- pipeline
     @property

@@ -2,7 +2,7 @@
 
 The paper's swappable-co-processor claim, as testable properties:
 
-  * ref / jnp / pallas(interpret=True) are **bit-identical** for qmatmul and
+  * ref / jnp / pallas (interpreted) are **bit-identical** for qmatmul and
     qconv2d under every dependability policy — the integer hot path is exact
     mod 2^32, so where the accumulator is computed cannot change it.
   * The fused pallas checksum (emitted as a second kernel output) satisfies
@@ -102,17 +102,17 @@ def test_pallas_acc_kernels_multiblock_with_tails():
     rng = np.random.default_rng(31)
     x_q, w_q, _, _ = _mm_case(rng, m=33, k=130, n=70)
     want = jnp.matmul(x_q.astype(jnp.int32), w_q.astype(jnp.int32))
-    acc = qmatmul_acc(x_q, w_q, block_m=16, block_n=32, block_k=48,
-                      interpret=True)
+    acc = qmatmul_acc(x_q, w_q, block_m=16, block_n=32, block_k=48)
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(want))
     w_check = abft.checksum_vector(w_q)
     acc, got = qmatmul_acc_checksum(x_q, w_q, w_check, block_m=16, block_n=32,
-                                    block_k=48, interpret=True)
+                                    block_k=48)
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(jnp.sum(want, axis=1)))
 
-    # conv: cout split across blocks, check channel emitted once per image
+    # conv: cout split across blocks (the check limbs with them), and one
+    # output row per tile, so each tile's halo spans several tiles
     x_c, w_c, _, _ = _conv_case(rng, h=8, w=8, cin=4, cout=10)
     zp = jnp.int32(2)
     from repro.kernels.dispatch import _pad_zp, _resolve_pads
@@ -122,7 +122,7 @@ def test_pallas_acc_kernels_multiblock_with_tails():
     wc = abft.conv_checksum_weight(w_c)
     acc, got = qconv2d_acc_checksum(xp, w_c, colsum, wc,
                                     zp.reshape(1), block_cout=4,
-                                    interpret=True)
+                                    block_rows=8)
     ref = dispatch.conv_acc(x_c, zp, w_c, backend="jnp")
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(got),

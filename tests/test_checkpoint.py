@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ckpt
 
 jax.config.update("jax_platform_name", "cpu")
@@ -76,9 +77,9 @@ def test_elastic_restore_new_mesh(tmp_path):
     state = small_state()
     specs = {"params": {"w": P("data", "model"), "b": P("model")},
              "opt": {"m": P("data", "model")}, "step": P()}
-    mesh_a = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_a = make_mesh((1, 1), ("data", "model"))
     ckpt.save(tmp_path, 5, state, specs=specs)
-    mesh_b = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_b = make_mesh((1, 1), ("data", "model"))
     step, restored = ckpt.restore(tmp_path, 5, mesh=mesh_b, specs=specs)
     assert step == 5
     np.testing.assert_array_equal(np.asarray(restored["params"]["w"]),

@@ -15,11 +15,12 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel import collectives as coll
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = jnp.arange(8 * 4 * 6, dtype=jnp.float32).reshape(8 * 4, 6)
 
 # ring all-gather == native all-gather (every shard holds the full array,

@@ -41,7 +41,7 @@ CASES = [
 def test_flash_matches_ref(B, H, KV, S, hd, window):
     q, k, v = qkv(jax.random.key(0), B, H, KV, S, hd)
     got = flash_attention(q, k, v, causal=True, window=window,
-                          block_q=64, block_k=64, interpret=True)
+                          block_q=64, block_k=64)
     want = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -49,7 +49,7 @@ def test_flash_matches_ref(B, H, KV, S, hd, window):
 
 def test_flash_bf16_io():
     q, k, v = qkv(jax.random.key(1), 1, 2, 2, 128, 32, jnp.bfloat16)
-    got = flash_attention(q, k, v, interpret=True, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, block_q=64, block_k=64)
     want = attention_ref(q, k, v)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -59,7 +59,7 @@ def test_flash_bf16_io():
 
 def test_flash_noncausal():
     q, k, v = qkv(jax.random.key(2), 1, 2, 2, 128, 32)
-    got = flash_attention(q, k, v, causal=False, interpret=True,
+    got = flash_attention(q, k, v, causal=False,
                           block_q=64, block_k=64)
     want = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -73,7 +73,7 @@ def test_ops_layout_adapter():
     q = jax.random.normal(ks[0], (B, S, H, hd))
     k = jax.random.normal(ks[1], (B, S, KV, hd))
     v = jax.random.normal(ks[2], (B, S, KV, hd))
-    got = flash_attn(q, k, v, interpret=True)
+    got = flash_attn(q, k, v)
     want = jnp.swapaxes(attention_ref(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)), 1, 2)
     assert got.shape == (B, S, H, hd)
@@ -84,9 +84,9 @@ def test_ops_layout_adapter():
 def test_block_shape_independence():
     """Different BlockSpec tilings must give identical results."""
     q, k, v = qkv(jax.random.key(4), 1, 2, 2, 256, 32)
-    a = flash_attention(q, k, v, interpret=True, block_q=64, block_k=64)
-    b = flash_attention(q, k, v, interpret=True, block_q=128, block_k=64)
-    c = flash_attention(q, k, v, interpret=True, block_q=64, block_k=128)
+    a = flash_attention(q, k, v, block_q=64, block_k=64)
+    b = flash_attention(q, k, v, block_q=128, block_k=64)
+    c = flash_attention(q, k, v, block_q=64, block_k=128)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-6, atol=1e-6)
 
@@ -116,7 +116,7 @@ def test_flash_bwd_matches_ref_grads(B, H, KV, S, hd, window):
                        * dout)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attn_diff(q, k, v, True, window, 64, 64, True)
+        return jnp.sum(flash_attn_diff(q, k, v, True, window, 64, 64)
                        * dout)
 
     g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
@@ -129,8 +129,8 @@ def test_flash_bwd_matches_ref_grads(B, H, KV, S, hd, window):
 
 def test_flash_fwd_lse_matches_plain_fwd():
     q, k, v = qkv(jax.random.key(9), 1, 2, 2, 128, 32)
-    o1 = flash_attention(q, k, v, interpret=True, block_q=64, block_k=64)
-    o2, lse = flash_attention_fwd_lse(q, k, v, interpret=True,
+    o1 = flash_attention(q, k, v, block_q=64, block_k=64)
+    o2, lse = flash_attention_fwd_lse(q, k, v,
                                       block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                rtol=1e-6, atol=1e-6)
@@ -168,10 +168,9 @@ def test_checked_kernel_two_tier_outputs(B, H, KV, S, hd, window):
     exact mod-2^32 bit checksum ``abft.output_row_checksums`` recomputes."""
     q, k, v = qkv(jax.random.key(11), B, H, KV, S, hd)
     plain = flash_attention(q, k, v, causal=True, window=window,
-                            block_q=64, block_k=64, interpret=True)
+                            block_q=64, block_k=64)
     out, check, csum = flash_attention_checked(
-        q, k, v, causal=True, window=window, block_q=64, block_k=64,
-        interpret=True)
+        q, k, v, causal=True, window=window, block_q=64, block_k=64)
     assert out.shape == (B, H, S, hd)
     assert check.shape == csum.shape == (B, H, S)
     assert csum.dtype == jnp.uint32
@@ -185,7 +184,7 @@ def test_checked_kernel_two_tier_outputs(B, H, KV, S, hd, window):
 def test_checked_kernel_bf16_checksum_is_exact():
     q, k, v = qkv(jax.random.key(12), 1, 2, 2, 128, 32, jnp.bfloat16)
     out, check, csum = flash_attention_checked(q, k, v, block_q=64,
-                                               block_k=64, interpret=True)
+                                               block_k=64)
     assert out.dtype == jnp.bfloat16
     assert bool(jnp.all(abft.output_row_checksums(out) == csum))
 
@@ -196,7 +195,7 @@ def test_output_bit_checksum_detects_every_flip():
     row — and only that row."""
     q, k, v = qkv(jax.random.key(13), 1, 2, 2, 128, 32)
     out, check, csum = flash_attention_checked(q, k, v, block_q=64,
-                                               block_k=64, interpret=True)
+                                               block_k=64)
     for bit in (0, 12, 23, 31):                  # mantissa → sign sweep
         bits = jax.lax.bitcast_convert_type(out, jnp.uint32)
         bits = bits.at[0, 1, 77, 5].set(bits[0, 1, 77, 5] ^ jnp.uint32(1 << bit))
@@ -218,7 +217,7 @@ def test_flash_attn_model_ragged_small_S(S):
     v = jax.random.normal(ks[2], (B, S, KV, hd))
     dout = jax.random.normal(ks[3], (B, S, H, hd))
 
-    got = flash_attn_model(q, k, v, interpret=True)
+    got = flash_attn_model(q, k, v)
     want = jnp.swapaxes(attention_ref(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v, 1, 2)), 1, 2)
@@ -226,7 +225,7 @@ def test_flash_attn_model_ragged_small_S(S):
                                rtol=2e-5, atol=2e-5)
 
     def f_model(q, k, v):
-        return jnp.sum(flash_attn_model(q, k, v, interpret=True) * dout)
+        return jnp.sum(flash_attn_model(q, k, v) * dout)
 
     def f_ref(q, k, v):
         return jnp.sum(jnp.swapaxes(attention_ref(

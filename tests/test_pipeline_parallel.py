@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.parallel import pipeline as pp
 
 jax.config.update("jax_platform_name", "cpu")
@@ -35,7 +36,7 @@ def sequential(param_list, mb):
 
 @pytest.mark.skipif(N_DEV < 2, reason="needs >=2 devices (set XLA flag)")
 def test_pipeline_matches_sequential():
-    mesh = jax.make_mesh((N_DEV,), ("stage",))
+    mesh = make_mesh((N_DEV,), ("stage",))
     n_stages, n_micro, mb, d = N_DEV, 6, 2, 8
     plist = make_stage_params(jax.random.key(0), n_stages, d)
     stacked = pp.stack_stage_params(plist)
@@ -49,7 +50,7 @@ def test_pipeline_matches_sequential():
 @pytest.mark.skipif(N_DEV < 2, reason="needs >=2 devices")
 def test_pipeline_grads_flow():
     """Autodiff through ppermute: every stage's params get nonzero grads."""
-    mesh = jax.make_mesh((N_DEV,), ("stage",))
+    mesh = make_mesh((N_DEV,), ("stage",))
     n_stages, n_micro, mb, d = N_DEV, 4, 2, 8
     plist = make_stage_params(jax.random.key(0), n_stages, d)
     stacked = pp.stack_stage_params(plist)

@@ -19,9 +19,10 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.parallel import pipeline as pp
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 d, n_micro, mb = 8, 6, 2
 ks = jax.random.split(jax.random.key(0), 4)
 plist = [{"w": jax.random.normal(k, (d, d)) * 0.3, "b": jnp.zeros((d,))}

@@ -47,7 +47,7 @@ def test_paper_table1_layers(kh, kw, cin, cout, h, w):
         rng, 1, h, w, cin, kh, kw, cout)
     got = ops.qconv2d_op(x_q, x_zp, w_q, colsum, bias, scale, out_zp,
                          stride=(1, 1), padding="SAME",
-                         use_kernel=True, interpret=True)
+                         use_kernel=True)
     want = qconv2d_ref(x_q, x_zp, w_q, bias, scale, out_zp,
                        stride=(1, 1), padding="SAME")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -61,7 +61,7 @@ def test_stride_padding_sweep(stride, padding):
         rng, 2, 17, 19, 8, 3, 3, 16)
     got = ops.qconv2d_op(x_q, x_zp, w_q, colsum, bias, scale, out_zp,
                          stride=stride, padding=padding,
-                         use_kernel=True, interpret=True)
+                         use_kernel=True)
     want = qconv2d_ref(x_q, x_zp, w_q, bias, scale, out_zp,
                        stride=stride, padding=padding)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -84,7 +84,7 @@ def test_qconv2d_random_cases(seed):
         rng, n, h, w, cin, kh, kw, cout)
     got = ops.qconv2d_op(x_q, x_zp, w_q, colsum, bias, scale, out_zp,
                          stride=(1, 1), padding="SAME",
-                         use_kernel=True, interpret=True)
+                         use_kernel=True)
     want = qconv2d_ref(x_q, x_zp, w_q, bias, scale, out_zp,
                        stride=(1, 1), padding="SAME")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -101,6 +101,6 @@ def test_qconv_act_end_to_end_accuracy():
     x_scale, x_zp = quant.affine_qparams(jnp.min(x), jnp.max(x))
     o_scale, o_zp = quant.affine_qparams(jnp.min(y_f), jnp.max(y_f))
     y_q = ops.qconv_act(x, params, x_scale, x_zp, o_scale, o_zp,
-                        use_kernel=True, interpret=True)
+                        use_kernel=True)
     rel = np.linalg.norm(np.asarray(y_q - y_f)) / np.linalg.norm(np.asarray(y_f))
     assert rel < 0.02, rel

@@ -47,7 +47,7 @@ def test_qmatmul_kernel_matches_ref(m, k, n):
     x_q, w_q, colsum, bias, scale, x_zp, out_zp = _random_case(rng, m, k, n)
     zps = jnp.stack([x_zp, out_zp])
 
-    got = qmatmul(x_q, w_q, colsum, bias, scale, zps, interpret=True)
+    got = qmatmul(x_q, w_q, colsum, bias, scale, zps)
     want = qmatmul_ref(x_q, x_zp, w_q, bias, scale, out_zp)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -58,7 +58,7 @@ def test_qmatmul_block_shape_sweep(bm, bn, bk):
     x_q, w_q, colsum, bias, scale, x_zp, out_zp = _random_case(rng, 96, 160, 96)
     zps = jnp.stack([x_zp, out_zp])
     got = qmatmul(x_q, w_q, colsum, bias, scale, zps,
-                  block_m=bm, block_n=bn, block_k=bk, interpret=True)
+                  block_m=bm, block_n=bn, block_k=bk)
     want = qmatmul_ref(x_q, x_zp, w_q, bias, scale, out_zp)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -89,7 +89,7 @@ def test_qlinear_act_end_to_end_accuracy():
     o_scale, o_zp = quant.affine_qparams(jnp.min(y_f), jnp.max(y_f))
 
     y_q = ops.qlinear_act(x, params, x_scale, x_zp, o_scale, o_zp,
-                          use_kernel=True, interpret=True)
+                          use_kernel=True)
     rel = np.linalg.norm(np.asarray(y_q - y_f)) / np.linalg.norm(np.asarray(y_f))
     assert rel < 0.02, rel
 

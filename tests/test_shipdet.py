@@ -31,7 +31,7 @@ def test_kernel_path_matches_ref_path():
     the paper's Fig. 4 validation applied end-to-end instead of per-layer."""
     specs, params, x = _setup()
     y_ref, _ = shipdet.forward(specs, params, x, use_kernel=False)
-    y_ker, _ = shipdet.forward(specs, params, x, use_kernel=True, interpret=True)
+    y_ker, _ = shipdet.forward(specs, params, x, use_kernel=True)
     np.testing.assert_array_equal(np.asarray(y_ref), np.asarray(y_ker))
 
 

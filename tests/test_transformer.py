@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tfm
 from repro.models.config import ArchConfig, MoEConfig
 
@@ -33,7 +34,7 @@ def small_moe(**kw) -> ArchConfig:
 
 
 def one_device_ctx():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return tfm.ShardCtx(mesh=mesh)
 
 
