@@ -116,7 +116,7 @@ def run_engine(cfg, params, trace, capacity, max_len, prefill_pad,
         "wall_s": round(dt, 4),
         "tokens_per_s": round(tokens / dt, 1),
         "occupancy": round(eng.stats.tokens_per_step() / capacity, 4),
-        **_latency_stats([r.finished_at - r.submitted_at for r in reqs]),
+        **_latency_stats([r.t_release - r.t_submit for r in reqs]),
     }
     return report, reqs, eng.compiled
 

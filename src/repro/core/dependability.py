@@ -41,6 +41,7 @@ policy, so the epilogue cannot drift between paths.
 from __future__ import annotations
 
 import enum
+import threading
 from typing import Optional
 
 import jax
@@ -99,6 +100,41 @@ class DependabilityStats:
     def to_host(stats: dict) -> dict:
         """Device scalars → plain ints, for JSON reports and log lines."""
         return {k: int(v) for k, v in stats.items()}
+
+
+class collect_stats:
+    """Trace-time sink for the stats of in-graph checks.
+
+    ``with collect_stats() as sink:`` around a model call, in the function
+    being traced, gathers what the model ``deposit``s while it is traced;
+    ``sink.stats`` is then their sum, an output the caller can return.  The
+    model keeps its signature: a model traced with no sink open drops its
+    stats.  Deposits must come from the trace the sink was opened in (a
+    model sums its per-layer stats through its layer scan and deposits
+    once, outside it)."""
+
+    _open = threading.local()
+
+    def __init__(self):
+        self.stats = DependabilityStats.zero()
+
+    def __enter__(self) -> "collect_stats":
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._open.stack.pop()
+
+    @classmethod
+    def deposit(cls, stats: dict) -> None:
+        """Add ``stats`` to the innermost open sink (none open: dropped)."""
+        stack = getattr(cls._open, "stack", None)
+        if stack:
+            sink = stack[-1]
+            sink.stats = DependabilityStats.merge(sink.stats, stats)
 
 
 def _bump(stats: dict, detected, corrected, recovered=False) -> dict:
@@ -242,16 +278,20 @@ def dependable_matmul_acc(
         row_bad = jnp.sum(acc, axis=1) != want
         detected = jnp.any(row_bad)
 
+        # the re-verify runs inside the recovery branch, so a clean pass
+        # pays for no second row sum
+        def reverified(a):
+            return a, jnp.all(jnp.sum(a, axis=1) == want)
+
         if policy == Policy.ABFT:
             def recover(a):
                 fresh = be.matmul_acc(x_q, w_q)
-                return jnp.where(row_bad[:, None], fresh, a)
-            acc = jax.lax.cond(detected, recover, lambda a: a, acc)
+                return reverified(jnp.where(row_bad[:, None], fresh, a))
         else:
-            def rollback(_):
-                return be.matmul_acc(x_q, w_q)
-            acc = jax.lax.cond(detected, rollback, lambda a: a, acc)
-        healed = detected & jnp.all(jnp.sum(acc, axis=1) == want)
+            def recover(_):
+                return reverified(be.matmul_acc(x_q, w_q))
+        acc, healed = jax.lax.cond(detected, recover,
+                                   lambda a: (a, jnp.bool_(False)), acc)
         corrected = healed if policy == Policy.ABFT else False
         recovered = healed if policy == Policy.CKPT else False
         return acc, _bump(stats, detected, corrected, recovered)

@@ -81,7 +81,7 @@ def measure_serving(cfg, *, batch: int = 8, reps: int = 30,
     from repro.core import abft as abft_mod
     from repro.core.policy_map import PolicyMap, PolicyRule
     from repro.models import api as model_api
-    from repro.runtime.dataflow import _decode_window_fn
+    from repro.runtime.dataflow import _decode_window_fn, decode_step_fn
     rng = np.random.default_rng(seed)
     cfg = model_api.with_backend(cfg, backend)
     params = model_api.init_params(cfg, jax.random.key(seed))
@@ -94,12 +94,7 @@ def measure_serving(cfg, *, batch: int = 8, reps: int = 30,
 
     def window_for(policy_map):
         mcfg = model_api.with_policy_map(cfg, policy_map)
-
-        def _step(p, tok, cache):
-            logits, cache = model_api.decode_step(mcfg, p, tok, cache)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-        win = _decode_window_fn(jax.jit(_step), n_steps, eos_id=-1,
+        win = _decode_window_fn(decode_step_fn(mcfg), n_steps, eos_id=-1,
                                 max_len=max_len)
         cache = model_api.init_cache(mcfg, batch, max_len)
         args = (params, tokens, cache, rem, pos, act)

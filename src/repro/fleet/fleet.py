@@ -423,6 +423,7 @@ class Fleet:
         the loser of a backup race is cancelled wherever it still runs, so
         its eventual release is suppressed."""
         req = rec.req if req is None else req
+        req.t_release = time.perf_counter()
         rec.released = True
         self.released[rec.req.uid] = req
         self.metrics.observe_release(self.tick_no - rec.submitted_tick,
@@ -650,7 +651,7 @@ class Fleet:
                 f"(replay budget exhausted)")
             return
         rec.req.output = None
-        rec.req.finished_at = 0.0
+        rec.req.clear_stamps("t_submit")
         rec.primary_done = rec.shadow_done = False
         primary = self.router.pick(rec.req.uid, self.replicas)
         if primary is None:

@@ -138,6 +138,7 @@ def flash_attention(
         functools.partial(_flash_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k,
                           window=window, causal=causal),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), q_map),
@@ -298,6 +299,7 @@ def flash_attention_checked(
         functools.partial(_flash_checked_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k,
                           window=window, causal=causal),
+        name="flash_attention_checked",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), q_map),
@@ -546,6 +548,7 @@ def flash_attention_fwd_lse(q, k, v, *, causal=True, window=None,
         functools.partial(_flash_fwd_lse_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal),
+        name="flash_attention_fwd_lse",
         grid=grid,
         in_specs=[pl.BlockSpec((1, block_q, hd), q_map),
                   pl.BlockSpec((1, block_k, hd), kv_map),
@@ -601,6 +604,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         functools.partial(_flash_bwd_dq_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal),
+        name="flash_attention_bwd_dq",
         grid=(B * H, nq, nk),
         in_specs=[pl.BlockSpec((1, block_q, hd), q_map),
                   pl.BlockSpec((1, block_k, hd), kv_map),
@@ -637,6 +641,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, seq_len=S,
                           block_q=block_q, block_k=block_k, window=window,
                           causal=causal, n_q_steps=nq),
+        name="flash_attention_bwd_dkv",
         grid=(B * KV, nk, G * nq),
         in_specs=[pl.BlockSpec((1, block_q, hd), q_map2),
                   pl.BlockSpec((1, block_k, hd), kv_map2),

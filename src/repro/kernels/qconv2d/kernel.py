@@ -121,8 +121,9 @@ def _conv_slabs(xp, kh, kw, stride, block_rows):
 
 
 def _conv_call(kernel, xp, w_q, operands, operand_specs, out_dtype, *,
-               stride, block_cout, block_rows):
-    """Run one conv kernel over the slabs; crop back to (N, OH, OW, Cout)."""
+               stride, block_cout, block_rows, name):
+    """Run one conv kernel over the slabs; crop back to (N, OH, OW, Cout).
+    ``name`` names the kernel call in the compiled program."""
     n = xp.shape[0]
     kh, kw, cin, cout = w_q.shape
     assert xp.shape[3] == cin, (xp.shape, w_q.shape)
@@ -131,6 +132,7 @@ def _conv_call(kernel, xp, w_q, operands, operand_specs, out_dtype, *,
     block_cout = min(block_cout, cout)
     out = pallas_call(
         functools.partial(kernel, stride=stride, wph=wph, rows=rows),
+        name=name,
         grid=(n, t, pl.cdiv(cout, block_cout)),
         in_specs=[
             pl.BlockSpec((1, 1) + slabs.shape[2:],
@@ -170,10 +172,18 @@ def qconv2d_acc(
 
     ``block_rows`` bounds the flattened output rows (output rows × phase
     width) one grid step computes."""
+    return _acc_call(x_q, w_q, colsum, zp, stride=stride,
+                     block_cout=block_cout, block_rows=block_rows,
+                     name="qconv2d_acc")
+
+
+def _acc_call(x_q, w_q, colsum, zp, *, stride, block_cout, block_rows,
+              name):
     return _conv_call(
         _qconv2d_acc_kernel, x_q, w_q, (colsum.reshape(1, -1), zp),
         lambda bc: [_row_spec(bc), _SCALARS], jnp.int32,
-        stride=stride, block_cout=block_cout, block_rows=block_rows)
+        stride=stride, block_cout=block_cout, block_rows=block_rows,
+        name=name)
 
 
 @functools.partial(
@@ -200,8 +210,9 @@ def qconv2d_acc_checksum(
     w_ext = jnp.concatenate([w_q, limbs], axis=3)
     colsum_ext = jnp.concatenate(
         [colsum, jnp.sum(limbs.astype(jnp.int32), axis=(0, 1, 2))])
-    acc = qconv2d_acc(x_q, w_ext, colsum_ext, zp, stride=stride,
-                      block_cout=block_cout, block_rows=block_rows)
+    acc = _acc_call(x_q, w_ext, colsum_ext, zp, stride=stride,
+                    block_cout=block_cout, block_rows=block_rows,
+                    name="qconv2d_acc_checksum")
     return acc[..., :cout], abft.from_limbs(acc[..., cout:])
 
 
@@ -225,4 +236,5 @@ def qconv2d(
         (colsum.reshape(1, -1), bias.reshape(1, -1), scale.reshape(1, -1),
          zps),
         lambda bc: [_row_spec(bc)] * 3 + [_SCALARS], jnp.int8,
-        stride=stride, block_cout=block_cout, block_rows=block_rows)
+        stride=stride, block_cout=block_cout, block_rows=block_rows,
+        name="qconv2d")

@@ -156,6 +156,7 @@ def qmatmul_acc(
                                                 block_k)
     return pallas_call(
         functools.partial(_qmatmul_acc_kernel, k_total=K),
+        name="qmatmul_acc",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda m, n, k: (m, k)),
@@ -193,6 +194,7 @@ def qmatmul_acc_checksum(
                                                 block_k)
     acc, parts = pallas_call(
         functools.partial(_qmatmul_acc_checksum_kernel, k_total=K),
+        name="qmatmul_acc_checksum",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda m, n, k: (m, k)),
@@ -241,6 +243,7 @@ def qmatmul(
 
     return pallas_call(
         functools.partial(_qmatmul_kernel, k_total=K),
+        name="qmatmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda m, n, k: (m, k)),

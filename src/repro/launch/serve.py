@@ -67,7 +67,7 @@ def main():
     stats = eng.run()
     dt = time.time() - t0
 
-    lat = [r.finished_at - r.submitted_at for r in reqs if r.finished_at]
+    lat = [r.t_release - r.t_submit for r in reqs if r.t_release]
     print(f"[serve] {stats.tokens_out} tokens in {dt:.2f}s "
           f"({stats.tokens_out/dt:.1f} tok/s), steps={stats.steps}, "
           f"replays={stats.replays}")

@@ -155,48 +155,53 @@ def forward(specs: List[ConvSpec], params: List[Dict[str, Any]], x: jax.Array,
             (len(layer_backends), len(specs))
     hook_layer = len(specs) // 2 if inject_layer is None else inject_layer
     for i, (s, p) in enumerate(zip(specs, params)):
-        stride = (s.stride, s.stride)
-        layer_be = layer_backends[i]
-        # uniform accumulator injection site: the mid-layer int32 accumulator
-        # is reachable under every policy, so fault-injection campaigns
-        # measure all policies on the same hook
-        layer_inject = inject if i == hook_layer else None
-        if policy_map is not None:
-            layer_policy, pm_backend = policy_map.resolve(s.name)
-            layer_be = pm_backend or layer_be
-            in_op_policy = layer_policy
-        else:
-            layer_policy = policy
-            # ABFT and CKPT run inside the op (checksum detect; recompute-
-            # vs rollback-recover); NMR policies replicate at the network
-            # level, so their per-layer call is the plain path
-            in_op_policy = policy if policy in (Policy.ABFT, Policy.CKPT) \
-                else Policy.NONE
-        if layer_policy != Policy.NONE or layer_inject is not None \
-                or layer_be is not None:
-            x_q = quant.quantize(x, p["in_scale"], p["in_zp"])
-            bias_i32 = jnp.round(
-                p["qconv"].bias_f / (p["in_scale"] * p["qconv"].w_scale)
-            ).astype(jnp.int32)
-            rq = quant.requant_scale(p["in_scale"], p["qconv"].w_scale,
-                                     p["out_scale"])
-            y_q, lstats = dependable_qconv2d(
-                in_op_policy,
-                x_q, p["in_zp"], p["qconv"].w_q, bias_i32, rq, p["out_zp"],
-                stride=stride, padding="SAME", inject=layer_inject,
-                backend=layer_be,
-                w_check=w_checks[i] if w_checks is not None else None,
-                ckpt=((x_q, golden_wq[i]) if golden_wq is not None
-                      else None))
-            x = (y_q.astype(jnp.float32) - p["out_zp"]) * p["out_scale"]
-            stats = DependabilityStats.merge(stats, lstats)
-        else:
-            x = qconv_ops.qconv_act(
-                x, p["qconv"], p["in_scale"], p["in_zp"],
-                p["out_scale"], p["out_zp"], stride=stride, padding="SAME",
-                use_kernel=use_kernel)
-        if i < len(specs) - 1:
-            x = jax.nn.relu(x)
+        # each layer's ops carry its name in the compiled program
+        with jax.named_scope(s.name):
+            stride = (s.stride, s.stride)
+            layer_be = layer_backends[i]
+            # uniform accumulator injection site: the mid-layer int32
+            # accumulator is reachable under every policy, so
+            # fault-injection campaigns measure all policies on one hook
+            layer_inject = inject if i == hook_layer else None
+            if policy_map is not None:
+                layer_policy, pm_backend = policy_map.resolve(s.name)
+                layer_be = pm_backend or layer_be
+                in_op_policy = layer_policy
+            else:
+                layer_policy = policy
+                # ABFT and CKPT run inside the op (checksum detect;
+                # recompute- vs rollback-recover); NMR policies replicate at
+                # the network level, so their per-layer call is the plain
+                # path
+                in_op_policy = policy if policy in (Policy.ABFT,
+                                                    Policy.CKPT) \
+                    else Policy.NONE
+            if layer_policy != Policy.NONE or layer_inject is not None \
+                    or layer_be is not None:
+                x_q = quant.quantize(x, p["in_scale"], p["in_zp"])
+                bias_i32 = jnp.round(
+                    p["qconv"].bias_f / (p["in_scale"] * p["qconv"].w_scale)
+                ).astype(jnp.int32)
+                rq = quant.requant_scale(p["in_scale"], p["qconv"].w_scale,
+                                         p["out_scale"])
+                y_q, lstats = dependable_qconv2d(
+                    in_op_policy,
+                    x_q, p["in_zp"], p["qconv"].w_q, bias_i32, rq,
+                    p["out_zp"], stride=stride, padding="SAME",
+                    inject=layer_inject, backend=layer_be,
+                    w_check=w_checks[i] if w_checks is not None else None,
+                    ckpt=((x_q, golden_wq[i]) if golden_wq is not None
+                          else None))
+                x = ((y_q.astype(jnp.float32) - p["out_zp"])
+                     * p["out_scale"])
+                stats = DependabilityStats.merge(stats, lstats)
+            else:
+                x = qconv_ops.qconv_act(
+                    x, p["qconv"], p["in_scale"], p["in_zp"],
+                    p["out_scale"], p["out_zp"], stride=stride,
+                    padding="SAME", use_kernel=use_kernel)
+            if i < len(specs) - 1:
+                x = jax.nn.relu(x)
     return x, stats
 
 

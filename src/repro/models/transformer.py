@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from repro.models import common
 from repro.models.config import ArchConfig
 from repro.core import quant
+from repro.core.dependability import DependabilityStats, collect_stats
 
 
 class ShardCtx(NamedTuple):
@@ -138,7 +139,7 @@ def _quantize_act(x):
 
 
 def _qdot(cfg: ArchConfig, x, bp, name):
-    """x @ W[name], W8A8 when quantized params are present.
+    """(x @ W[name], stats), W8A8 when quantized params are present.
 
     The int8 accumulator comes from the execution-backend registry
     (``cfg.backend``): jnp dot_general by default, the Pallas qmatmul
@@ -154,7 +155,12 @@ def _qdot(cfg: ArchConfig, x, bp, name):
     change how XLA fuses the bf16 ops around them, and XLA's excess bf16
     precision inside a fusion can then move the last bits.  The scan over
     layers means the assignment is per-matmul-name, uniform across the
-    layer stack (see core/policy_map.py)."""
+    layer stack (see core/policy_map.py).
+
+    ``stats`` is the DependabilityStats of the check the site ran (zeros
+    where it ran none)."""
+    from repro.core import dependability as dep
+    stats = DependabilityStats.zero()
     if name + "_q" in bp:
         from repro.kernels import dispatch
         x_q, x_s = _quantize_act(x)
@@ -168,19 +174,19 @@ def _qdot(cfg: ArchConfig, x, bp, name):
         x2 = with_layout_constraint(x_q.reshape(-1, x_q.shape[-1]),
                                     Layout(major_to_minor=(0, 1)))
         if cfg.policy_map is not None:
-            from repro.core import dependability as dep
             pol, pm_backend = cfg.policy_map.resolve("ffn." + name)
             be = pm_backend or cfg.backend
             if pol is dep.Policy.NONE:
                 acc = dispatch.matmul_acc(x2, w_q, backend=be)
             else:
-                acc, _ = dep.dependable_matmul_acc(pol, x2, w_q, backend=be)
+                acc, stats = dep.dependable_matmul_acc(pol, x2, w_q,
+                                                       backend=be)
         else:
             acc = dispatch.matmul_acc(x2, w_q, backend=cfg.backend)
         acc = acc.reshape(*lead, w_q.shape[-1])
         y = acc.astype(jnp.float32) * x_s * bp[name + "_s"]
-        return y.astype(x.dtype)
-    return x @ _w(cfg, bp[name])
+        return y.astype(x.dtype), stats
+    return x @ _w(cfg, bp[name]), stats
 
 
 def _qeinsum(cfg: ArchConfig, spec, x, bp, name):
@@ -280,6 +286,12 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict[str, Any]:
 
 def _attention(cfg: ArchConfig, bp, x, positions, ctx: Optional[ShardCtx]):
     """Pre-norm GQA attention (full-sequence / training / prefill)."""
+    with jax.named_scope("attention"):
+        return _attention_block(cfg, bp, x, positions, ctx)
+
+
+def _attention_block(cfg: ArchConfig, bp, x, positions,
+                     ctx: Optional[ShardCtx]):
     B, S, d = x.shape
     hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
@@ -345,10 +357,16 @@ def _attention_core(cfg: ArchConfig, q, k, v, positions, ctx):
     return fn(q, k, v)
 
 
-def _dense_ffn(cfg: ArchConfig, bp, x):
-    h = common.rms_norm(x, bp["ln2"], cfg.norm_eps)
-    act = jax.nn.silu(_qdot(cfg, h, bp, "wg")) * _qdot(cfg, h, bp, "wi")
-    return x + _qdot(cfg, act, bp, "wd")
+def _dense_ffn(cfg: ArchConfig, bp, x, stats):
+    """The FFN block; ``stats`` plus the checks of its three matmuls."""
+    with jax.named_scope("ffn"):
+        h = common.rms_norm(x, bp["ln2"], cfg.norm_eps)
+        g, sg = _qdot(cfg, h, bp, "wg")
+        i, si = _qdot(cfg, h, bp, "wi")
+        y, sd = _qdot(cfg, jax.nn.silu(g) * i, bp, "wd")
+        for s in (sg, si, sd):
+            stats = DependabilityStats.merge(stats, s)
+        return x + y, stats
 
 
 # --------------------------- MoE (shard_map EP) ----------------------------
@@ -484,8 +502,10 @@ def _moe_ffn_local(cfg: ArchConfig, bp, x, ctx: ShardCtx, mode: str = "ep"):
 
     # shared experts: plain dense FFN, tensor-parallel over model axis
     if m.n_shared_experts:
-        sact = jax.nn.silu(_qdot(cfg, h, bp, "ws_g")) * _qdot(cfg, h, bp, "ws_i")
-        sout = _qdot(cfg, sact, bp, "ws_o")
+        # the shared experts' check stats stay inside the expert layer
+        sact = (jax.nn.silu(_qdot(cfg, h, bp, "ws_g")[0])
+                * _qdot(cfg, h, bp, "ws_i")[0])
+        sout = _qdot(cfg, sact, bp, "ws_o")[0]
         combined = combined + jax.lax.psum(sout, ctx.model)
 
     aux = jax.lax.pmean(aux, ctx.dp + (ctx.model,))
@@ -566,8 +586,9 @@ def _moe_ffn_single(cfg: ArchConfig, bp, x):
     combined = jnp.zeros((n, d), out.dtype).at[gather_idx].add(
         jnp.where(filled[:, None], out, 0))
     if m.n_shared_experts:
-        sact = jax.nn.silu(_qdot(cfg, h, bp, "ws_g")) * _qdot(cfg, h, bp, "ws_i")
-        combined = combined + _qdot(cfg, sact, bp, "ws_o")
+        sact = (jax.nn.silu(_qdot(cfg, h, bp, "ws_g")[0])
+                * _qdot(cfg, h, bp, "ws_i")[0])
+        combined = combined + _qdot(cfg, sact, bp, "ws_o")[0]
     return x + combined.reshape(B, S, d).astype(x.dtype), aux, z_loss
 
 
@@ -593,7 +614,9 @@ def _remat_policy(cfg: ArchConfig):
 def forward(cfg: ArchConfig, params, tokens: jax.Array,
             ctx: Optional[ShardCtx] = None,
             embeds: Optional[jax.Array] = None) -> ForwardOut:
-    """tokens: (B, S) int32 — or embeds (B, S, d) for audio/vlm stub inputs."""
+    """tokens: (B, S) int32 — or embeds (B, S, d) for audio/vlm stub inputs.
+
+    The dense FFN checks' stats go to an open ``collect_stats`` sink."""
     if embeds is not None:
         x = embeds.astype(_cdt(cfg))
         B, S, _ = embeds.shape
@@ -604,6 +627,7 @@ def forward(cfg: ArchConfig, params, tokens: jax.Array,
     positions = jnp.arange(S)[None, :]
     aux = jnp.zeros((), jnp.float32)
     zl = jnp.zeros((), jnp.float32)
+    stats = DependabilityStats.zero()
 
     policy = _remat_policy(cfg)
 
@@ -621,10 +645,11 @@ def forward(cfg: ArchConfig, params, tokens: jax.Array,
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.NamedSharding(ctx.mesh, spec))
 
-    def dense_body(x, bp):
+    def dense_body(carry, bp):
+        x, stats = carry
         x = _attention(cfg, bp, x, positions, ctx)
-        x = _dense_ffn(cfg, bp, x)
-        return seq_sp(x), None
+        x, stats = _dense_ffn(cfg, bp, x, stats)
+        return (seq_sp(x), stats), None
 
     def moe_body(carry, bp):
         x, aux, zl = carry
@@ -640,9 +665,11 @@ def forward(cfg: ArchConfig, params, tokens: jax.Array,
         moe_body = jax.checkpoint(moe_body, policy=policy, prevent_cse=False)
 
     if params.get("dense_blocks") is not None:
-        x, _ = jax.lax.scan(dense_body, x, params["dense_blocks"])
+        (x, stats), _ = jax.lax.scan(dense_body, (x, stats),
+                                     params["dense_blocks"])
     if params.get("moe_blocks") is not None:
         (x, aux, zl), _ = jax.lax.scan(moe_body, (x, aux, zl), params["moe_blocks"])
+    collect_stats.deposit(stats)
 
     x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -712,6 +739,13 @@ def _block_decode(cfg: ArchConfig, bp, x, k_cache, v_cache, pos, T,
     """One block's single-token attention. x: (B, 1, d), pos: (B,) per-row
     positions (ragged continuous batching). ks/vs: int8-KV scale pages
     (B, T, KV) when cfg.quant_kv. Returns new x, cache pages (+ scales)."""
+    with jax.named_scope("attention"):
+        return _block_decode_attention(cfg, bp, x, k_cache, v_cache, pos, T,
+                                       ks, vs)
+
+
+def _block_decode_attention(cfg: ArchConfig, bp, x, k_cache, v_cache, pos,
+                            T, ks, vs):
     B = x.shape[0]
     hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
@@ -733,18 +767,20 @@ def _block_decode(cfg: ArchConfig, bp, x, k_cache, v_cache, pos, T,
     rows = jnp.arange(B)
     valid = jnp.minimum(pos + 1, T)                  # (B,)
     if ks is not None:                               # int8 KV cache
-        k_q, k_sc = _quantize_kv_rows(k[:, 0])       # (B, KV, hd), (B, KV)
-        v_q, v_sc = _quantize_kv_rows(v[:, 0])
-        k_cache = k_cache.at[rows, slot].set(k_q)
-        v_cache = v_cache.at[rows, slot].set(v_q)
-        ks = ks.at[rows, slot].set(k_sc)
-        vs = vs.at[rows, slot].set(v_sc)
+        with jax.named_scope("kv_update"):
+            k_q, k_sc = _quantize_kv_rows(k[:, 0])   # (B, KV, hd), (B, KV)
+            v_q, v_sc = _quantize_kv_rows(v[:, 0])
+            k_cache = k_cache.at[rows, slot].set(k_q)
+            v_cache = v_cache.at[rows, slot].set(v_q)
+            ks = ks.at[rows, slot].set(k_sc)
+            vs = vs.at[rows, slot].set(v_sc)
         o = common.decode_attention(q, k_cache, v_cache, valid,
                                     k_scale=ks, v_scale=vs)
         out = (x + (o.reshape(B, 1, H * hd) @ _w(cfg, bp["wo"])).astype(x.dtype))
         return out, k_cache, v_cache, ks, vs
-    k_cache = k_cache.at[rows, slot].set(k[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[rows, slot].set(v[:, 0].astype(v_cache.dtype))
+    with jax.named_scope("kv_update"):
+        k_cache = k_cache.at[rows, slot].set(k[:, 0].astype(k_cache.dtype))
+        v_cache = v_cache.at[rows, slot].set(v[:, 0].astype(v_cache.dtype))
     o = common.decode_attention(q, k_cache, v_cache, valid)
     x = x + (o.reshape(B, 1, H * hd) @ _w(cfg, bp["wo"])).astype(x.dtype)
     return x, k_cache, v_cache, None, None
@@ -796,6 +832,9 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
     under donation.  (A carried-full-buffer variant with a dynamic layer
     index was measured 2.8× WORSE: scatter through a traced layer index on
     the (L,·) buffer lowers to full-buffer masked selects per layer.)
+
+    The dense FFN checks' stats, summed over layers, go to an open
+    ``collect_stats`` sink.
     """
     if embed is not None:
         x = embed[:, None, :].astype(_cdt(cfg))
@@ -810,7 +849,8 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
     qkv_cache = cfg.quant_kv
 
     def make_body(moe: bool):
-        def body(x, layer):
+        def body(carry, layer):
+            x, stats = carry
             if qkv_cache:
                 bp, kc, vc, ksp, vsp = layer
             else:
@@ -823,8 +863,8 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
                 else:
                     x, _, _ = _moe_ffn_single(cfg, bp, x)
             else:
-                x = _dense_ffn(cfg, bp, x)
-            return x, ((kc, vc, ksp, vsp) if qkv_cache else (kc, vc))
+                x, stats = _dense_ffn(cfg, bp, x, stats)
+            return (x, stats), ((kc, vc, ksp, vsp) if qkv_cache else (kc, vc))
         return body
 
     def xs_for(blocks, lo, hi):
@@ -845,16 +885,19 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
         new_k.append(kc)
         new_v.append(vc)
 
+    carry = (x, DependabilityStats.zero())
     if params.get("dense_blocks") is not None:
         nd = jax.tree_util.tree_leaves(params["dense_blocks"])[0].shape[0]
-        x, ys = jax.lax.scan(make_body(False), x,
-                             xs_for(params["dense_blocks"], 0, nd))
+        carry, ys = jax.lax.scan(make_body(False), carry,
+                                 xs_for(params["dense_blocks"], 0, nd))
         collect(ys)
     if params.get("moe_blocks") is not None:
-        x, ys = jax.lax.scan(make_body(True), x,
-                             xs_for(params["moe_blocks"], n_dense,
-                                    cfg.n_layers))
+        carry, ys = jax.lax.scan(make_body(True), carry,
+                                 xs_for(params["moe_blocks"], n_dense,
+                                        cfg.n_layers))
         collect(ys)
+    x, stats = carry
+    collect_stats.deposit(stats)
 
     x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -877,6 +920,10 @@ def prefill(cfg: ArchConfig, params, tokens: jax.Array, max_len: int,
     Implemented as forward() for logits + a lightweight second pass that
     recomputes per-layer K/V into the cache (scan, no attention) — keeps one
     code path for attention math.  Returns (logits, cache).
+
+    Both passes run the FFN, so an open ``collect_stats`` sink receives two
+    FFN checks' stats per matmul and layer: the forward's and the second
+    pass's.
     """
     out = forward(cfg, params, tokens, ctx, embeds=embeds)
     B, S = (embeds.shape[:2] if embeds is not None else tokens.shape)
@@ -890,20 +937,22 @@ def prefill(cfg: ArchConfig, params, tokens: jax.Array, max_len: int,
     else:
         x = params["embed"][tokens].astype(_cdt(cfg))
 
-    def kv_body(x, bp):
-        h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
-        k = (h @ _w(cfg, bp["wk"])).reshape(B, S, KV, hd)
-        v = (h @ _w(cfg, bp["wv"])).reshape(B, S, KV, hd)
-        if cfg.use_bias:
-            k = k + _w(cfg, bp["bk"]).reshape(1, 1, KV, hd)
-            v = v + _w(cfg, bp["bv"]).reshape(1, 1, KV, hd)
-        if cfg.qk_norm:
-            k = common.rms_norm(k, bp["k_norm"], cfg.norm_eps)
-        k = common.apply_rope(k, positions, cfg.rope_theta)
+    def kv_body(carry, bp):
+        x, stats = carry
+        with jax.named_scope("kv_update"):
+            h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
+            k = (h @ _w(cfg, bp["wk"])).reshape(B, S, KV, hd)
+            v = (h @ _w(cfg, bp["wv"])).reshape(B, S, KV, hd)
+            if cfg.use_bias:
+                k = k + _w(cfg, bp["bk"]).reshape(1, 1, KV, hd)
+                v = v + _w(cfg, bp["bv"]).reshape(1, 1, KV, hd)
+            if cfg.qk_norm:
+                k = common.rms_norm(k, bp["k_norm"], cfg.norm_eps)
+            k = common.apply_rope(k, positions, cfg.rope_theta)
         # recompute the block output to feed the next layer
         x = _attention(cfg, bp, x, positions, ctx)
         if "wd" in bp or "wd_q" in bp:   # dense block (float or W8A8)
-            x = _dense_ffn(cfg, bp, x)
+            x, stats = _dense_ffn(cfg, bp, x, stats)
         elif ctx is not None:
             x, _, _ = _moe_ffn(cfg, bp, x, ctx)
         else:
@@ -912,17 +961,19 @@ def prefill(cfg: ArchConfig, params, tokens: jax.Array, max_len: int,
         sl = jnp.maximum(S - T, 0)
         kk = jax.lax.dynamic_slice_in_dim(k, sl, min(T, S), axis=1)
         vv = jax.lax.dynamic_slice_in_dim(v, sl, min(T, S), axis=1)
-        return x, (kk, vv)
+        return (x, stats), (kk, vv)
 
     ks, vs = [], []
+    carry = (x, DependabilityStats.zero())
     if params.get("dense_blocks") is not None:
-        x, (kk, vv) = jax.lax.scan(kv_body, x, params["dense_blocks"])
+        carry, (kk, vv) = jax.lax.scan(kv_body, carry, params["dense_blocks"])
         ks.append(kk)
         vs.append(vv)
     if params.get("moe_blocks") is not None:
-        x, (kk, vv) = jax.lax.scan(kv_body, x, params["moe_blocks"])
+        carry, (kk, vv) = jax.lax.scan(kv_body, carry, params["moe_blocks"])
         ks.append(kk)
         vs.append(vv)
+    collect_stats.deposit(carry[1])
     k_all = jnp.concatenate(ks)           # (L, B, min(T,S), KV, hd)
     v_all = jnp.concatenate(vs)
 

@@ -1,30 +1,30 @@
-"""Deterministic per-request span tracing — Chrome ``trace_event`` export.
+"""Span tracing on two clocks.
 
-Answers *where a request spent its time*: every request flowing through the
-streaming executor gets one span per pipeline stage (admit → prefill →
-decode → certify), plus counter tracks for queue depth and decode-slot
-occupancy sampled once per pump cycle.  The file a trace dumps to is the
-Chrome/Perfetto ``trace_event`` JSON format, so ``ui.perfetto.dev`` (or
-``chrome://tracing``) renders the pipeline directly — one track per stage,
-one slice per request-stage residency.
+The program keeps two kinds of span, for two questions:
 
-Determinism is the design constraint: spans are keyed on the executor's
-**tick clock** (cooperative pump cycles), not the wall clock, so two runs
-with the same seed produce *byte-identical* trace files — the property the
-dependability campaigns rely on for replay debugging, asserted in
-``tests/test_obs.py``.  ``wall_clock=True`` opt-in adds wall-time
-annotations to span args (useful for real profiling, destroys
-byte-identity; default off).
+  * **tick spans** (``SpanTracer``) answer *where a request spent its
+    pump cycles*: every request flowing through the streaming executor gets
+    one span per pipeline stage (admit → prefill → decode → certify), plus
+    counter tracks for queue depth and decode-slot occupancy sampled once
+    per pump cycle, exported as Chrome ``trace_event`` JSON
+    (``ui.perfetto.dev`` renders it: one track per stage, one slice per
+    request-stage residency).  They are keyed on the executor's **tick
+    clock** (cooperative pump cycles), never on a wall clock, so two runs
+    with the same seed produce *byte-identical* files — the replay-debugging
+    property the dependability campaigns rely on, asserted in
+    ``tests/test_obs.py``.
+  * **profiler spans** (``span``) answer *where the time went*: a scoped
+    ``jax.profiler.TraceAnnotation`` on the host thread, so it lands in the
+    profiler's trace beside the device's operations, on the same clock.
+    The engine opens one at every layer boundary of a pump (``engine/…``
+    names, see ``runtime/dataflow.py``).  When no profile is being taken a
+    span costs one TraceMe check (about a microsecond on the host).
 
-Cost model: tracing must be a pure observer —
+Cost of tick spans: disabled (``tracer=None`` on the executor) they are a
+handful of ``if x is None`` branches; enabled they are dict appends on
+host-side stage transitions only (never inside jitted code).
 
-  * disabled (``tracer=None`` on the executor) it is a handful of ``if x is
-    None`` branches: zero allocations, nothing measurable;
-  * enabled it is dict appends on host-side stage transitions only (never
-    inside jitted code), budgeted at < 3 % tokens/s on the serving bench
-    (asserted in CI).
-
-Span model (Chrome ``ph`` phases):
+Tick-span model (Chrome ``ph`` phases):
 
   ``X`` complete events — one per (request uid, stage) residency, ``ts`` =
         entry tick, ``dur`` = ticks resident, ``args`` carry uid and
@@ -34,20 +34,38 @@ Span model (Chrome ``ph`` phases):
   ``M`` metadata — process/thread naming so stage tracks sort correctly.
 
 Ticks are exported as microseconds 1:1 (Perfetto needs a time unit; one
-tick = 1 µs nominal).  In wall-clock mode spans additionally carry
-``wall_ts``/``wall_dur`` (seconds) in their args.
+tick = 1 µs nominal).
 """
 from __future__ import annotations
 
 import json
 import pathlib
-import time
-from typing import Dict, List, Optional, Tuple
+from collections import deque
+from typing import Dict, List, Tuple
+
+import jax
 
 # canonical stage → trace-track (tid) assignment; release is an instant on
 # the certify track's successor so it sorts last
 STAGE_TIDS = {"admit": 1, "prefill": 2, "decode": 3, "certify": 4,
               "release": 5}
+
+
+def span(name: str, **args):
+    """A scoped host span on the profiler's clock (``with span(...):``).
+
+    ``args`` ride along as the event's stats; the event keeps ``name``."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+# The stage stamps of the requests serving engines released in this
+# process, oldest first, bounded: one ``(uid, prompt_len, n_out, t_submit,
+# t_prefill, t_join, t_finish, t_release)`` tuple a release, the stamps on
+# ``time.perf_counter()``.  Read after a run (by a benchmark, say) to split
+# each request's time into waiting for a slot and being served.  It is
+# process-wide, as a profile is, so that a reader holding no handle to the
+# engine (a metric computed from a run's output) finds it.
+RELEASED: deque = deque(maxlen=1 << 16)
 
 
 class SpanTracer:
@@ -56,18 +74,15 @@ class SpanTracer:
     The owner (``StreamingExecutor``) calls ``tick_to(t)`` as its clock
     advances, ``open_span``/``close_span`` at stage transitions, ``instant``
     for point events, and ``counter`` for per-tick level samples.  Nothing
-    here reads a clock of its own in deterministic mode.
+    here reads a clock of its own.
     """
 
-    def __init__(self, wall_clock: bool = False, name: str = "engine",
-                 pid: int = 0):
-        self.wall_clock = wall_clock
+    def __init__(self, name: str = "engine", pid: int = 0):
         self.name = name
         self.pid = pid
         self.tick = 0
         self.events: List[dict] = []
         self._open: Dict[Tuple[int, str], dict] = {}   # (uid, stage) -> span
-        self._t0 = time.perf_counter() if wall_clock else 0.0
         self._emit_metadata()
 
     # ------------------------------------------------------------ plumbing
@@ -83,9 +98,6 @@ class SpanTracer:
                                 "name": "thread_sort_index",
                                 "args": {"sort_index": tid}})
 
-    def _wall(self) -> float:
-        return time.perf_counter() - self._t0
-
     def tick_to(self, tick: int) -> None:
         self.tick = tick
 
@@ -94,8 +106,6 @@ class SpanTracer:
         """Begin a (uid, stage) residency at the current tick.  Re-opening
         an open span restarts it (rollback replays re-enter a stage)."""
         span = {"uid": uid, "stage": stage, "ts": self.tick, "args": args}
-        if self.wall_clock:
-            span["wall_ts"] = self._wall()
         self._open[(uid, stage)] = span
 
     def close_span(self, uid: int, stage: str, **args) -> None:
@@ -111,9 +121,6 @@ class SpanTracer:
               "name": stage, "cat": "request",
               "ts": span["ts"], "dur": self.tick - span["ts"],
               "args": merged}
-        if self.wall_clock:
-            ev["args"]["wall_ts"] = span["wall_ts"]
-            ev["args"]["wall_dur"] = self._wall() - span["wall_ts"]
         self.events.append(ev)
 
     def cancel_span(self, uid: int, stage: str) -> None:
@@ -124,8 +131,6 @@ class SpanTracer:
         ev = {"ph": "i", "pid": self.pid,
               "tid": STAGE_TIDS.get(stage, 9), "name": name,
               "cat": "event", "ts": self.tick, "s": "t", "args": args}
-        if self.wall_clock:
-            ev["args"]["wall_ts"] = self._wall()
         self.events.append(ev)
 
     def counter(self, name: str, **series) -> None:
@@ -151,10 +156,7 @@ class SpanTracer:
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
-            "metadata": {
-                "clock": "ticks" if not self.wall_clock else "ticks+wall",
-                "tracer": self.name,
-            },
+            "metadata": {"clock": "ticks", "tracer": self.name},
         }
 
     def to_bytes(self) -> bytes:
@@ -178,14 +180,11 @@ def merge_traces(tracers) -> dict:
     events: List[dict] = []
     for tr in tracers:
         events.extend(tr.to_chrome_trace()["traceEvents"])
-    wall = any(tr.wall_clock for tr in tracers)
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",
-        "metadata": {
-            "clock": "ticks" if not wall else "ticks+wall",
-            "tracer": "+".join(tr.name for tr in tracers),
-        },
+        "metadata": {"clock": "ticks",
+                     "tracer": "+".join(tr.name for tr in tracers)},
     }
 
 

@@ -43,21 +43,42 @@ Device-fault recovery (snapshot/rollback, decode-state scrubbing) lives at
 the executor level because a consistent restore spans admit bookkeeping and
 decode state together; see docs/streaming.md and docs/recovery.md.
 
-Observability (``repro.obs``) threads through the pipeline as a pure
-observer — all three hooks default off and cost nothing when absent:
+Observability (``repro.obs``) runs on two clocks:
 
-  * ``tracer=``    a ``SpanTracer``: per-request spans for every stage
-    residency (admit → prefill → decode → certify) plus release instants
-    and per-pump queue-depth / slot-occupancy counter tracks, keyed on the
-    executor's deterministic **tick clock** (one tick per cooperative pump
-    cycle).  Exports Chrome ``trace_event`` JSON; byte-identical across
-    same-seed runs.
-  * ``event_log=`` an ``EventLog``: typed dependability events (strike /
-    detection / rollback) with fault provenance, the substrate campaign
-    reports reconstruct injection→detection→recovery timelines from.
-  * ``metrics=``   a ``Registry``: streaming counters/gauges/histograms
-    (released requests, release-latency ticks, queue depths) — bounded
-    memory regardless of run length.
+  * the **profiler's clock**, always on: every pump opens a host span
+    (``obs.span``, a ``jax.profiler.TraceAnnotation``) at each layer
+    boundary — ``engine/state_scrub`` and ``engine/storage_scrub`` (each
+    with ``engine/rollback`` inside when it restores), ``engine/admit``,
+    per prefilled request ``engine/prefill`` and
+    ``engine/prefill_readback``, ``engine/join``, ``engine/snapshot``,
+    ``engine/decode_window`` (or ``engine/decode_step``),
+    ``engine/decode_readback``, ``engine/decode_replay``,
+    ``engine/state_refresh``, ``engine/certify``, ``engine/release`` (and
+    ``engine/report`` around ``Engine.dependability_report``'s readback) —
+    so a profile attributes the device's idle time to what the host was
+    doing.
+    Every request carries five stage stamps on ``time.perf_counter()``
+    (``Request.t_submit`` … ``t_release``), and each release logs them to
+    ``obs.trace.RELEASED``.
+  * the executor's deterministic **tick clock** (one tick per cooperative
+    pump cycle), through three optional hooks that cost nothing when
+    absent:
+
+    - ``tracer=``    a ``SpanTracer``: per-request spans for every stage
+      residency (admit → prefill → decode → certify) plus release instants
+      and per-pump queue-depth / slot-occupancy counter tracks.  Exports
+      Chrome ``trace_event`` JSON; byte-identical across same-seed runs.
+    - ``event_log=`` an ``EventLog``: typed dependability events (strike /
+      detection / rollback) with fault provenance, the substrate campaign
+      reports reconstruct injection→detection→recovery timelines from.
+    - ``metrics=``   a ``Registry``: streaming counters/gauges/histograms
+      (released requests, release-latency ticks, queue depths) — bounded
+      memory regardless of run length.
+
+The FFN's in-graph ABFT checks report through the programs' outputs: the
+prefill, decode-step and decode-window programs each return the summed
+DependabilityStats of the checks they ran, which the executor folds into
+its dependability counters.
 
 See docs/observability.md.
 """
@@ -74,23 +95,65 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import abft
-from repro.core.dependability import DependabilityStats
+from repro.core.dependability import DependabilityStats, collect_stats
 from repro.models import api as model_api
 from repro.models.config import ArchConfig
+from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 
-# decode-state checksums: the storage-scrub identity applied to the live
-# KV cache / recurrent state + token buffer; jitted once per cache structure
-_state_checksums = jax.jit(abft.storage_checksums)
+# Every program the engine runs is a named function, so its XLA module
+# reads ``jit_<name>`` in a profile: ``jit_engine_prefill``,
+# ``jit_engine_decode_step``, ``jit_engine_decode_window``,
+# ``jit_splice_slot``, ``jit_state_checksums``, ``jit_storage_verify``
+# (the deploy-time weight checksums stay ``jit_storage_checksums``).
+
+
+_storage_checksums = jax.jit(abft.storage_checksums)
 
 
 @jax.jit
-def _splice_slot(batch_cache, one_cache, tokens, slot, first_tok, n):
+def state_checksums(state):
+    """Decode-state checksums: the storage-scrub identity applied to the
+    live KV cache / recurrent state + token buffer (scrub, refresh and
+    snapshot); jitted once per cache structure."""
+    return abft.storage_checksums(state)
+
+
+@jax.jit
+def storage_verify(params, checks):
+    """The in-serve weight verify against the golden storage checksums."""
+    return abft.verify_storage(params, checks)
+
+
+@jax.jit
+def splice_slot(batch_cache, one_cache, tokens, slot, first_tok, n):
     """Join-time slot splice, fused into one compiled call: write the
     prefilled request's cache rows and first token into ``slot`` of the live
     batch.  Module-level jit so every executor (and every fleet replica)
     shares one compile cache entry per cache structure."""
     cache = model_api.cache_write_slot(batch_cache, one_cache, slot, n)
     return cache, tokens.at[slot].set(first_tok)
+
+
+def decode_step_fn(cfg: ArchConfig):
+    """The jitted decode step ``(params, tokens, cache) -> (next tokens,
+    cache, stats)``: greedy next token of every slot, and the summed
+    DependabilityStats of the in-graph FFN checks the step ran."""
+    def engine_decode_step(params, tokens, cache):
+        with collect_stats() as sink:
+            logits, cache = model_api.decode_step(cfg, params, tokens, cache)
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
+                sink.stats)
+    return jax.jit(engine_decode_step)
+
+
+def prefill_fn(cfg: ArchConfig, max_len: int):
+    """The jitted prefill ``(params, tokens) -> (logits, cache, stats)``."""
+    def engine_prefill(params, tokens):
+        with collect_stats() as sink:
+            logits, cache = model_api.prefill(cfg, params, tokens, max_len)
+        return logits, cache, sink.stats
+    return jax.jit(engine_prefill)
 
 
 def _checks_equal(a, b) -> bool:
@@ -100,9 +163,10 @@ def _checks_equal(a, b) -> bool:
 
 
 # Multi-step decode windows: one jitted N-step scan per (decode fn, N,
-# eos, max_len).  Keyed on the decode fn *object* (a strong reference is
-# kept so ids cannot be recycled), which is how fleet replicas / benchmark
-# reps sharing a ``compiled`` pair also share one window compilation.
+# eos, max_len), over a ``decode_step_fn`` program.  Keyed on the decode fn
+# *object* (a strong reference is kept so ids cannot be recycled), which is
+# how fleet replicas / benchmark reps sharing a ``compiled`` pair also share
+# one window compilation.
 _DECODE_WINDOW_CACHE: dict = {}
 
 
@@ -115,29 +179,32 @@ def _decode_window_fn(decode_fn, n_steps: int, eos_id: int, max_len: int):
     once per window instead of once per step.  Every slot steps every
     inner step (slot rows are independent and a later join splices whole
     rows), which is exactly the per-step engine's behavior for slots that
-    finished but have not been re-filled yet.
+    finished but have not been re-filled yet.  Returns (carry, (next
+    tokens, finished masks), stats summed over the window's N steps).
     """
     key = (id(decode_fn), n_steps, eos_id, max_len)
     hit = _DECODE_WINDOW_CACHE.get(key)
     if hit is not None:
         return hit[1]
 
-    def _window(params, tokens, cache, remaining, pos, active):
+    def engine_decode_window(params, tokens, cache, remaining, pos, active):
         def body(carry, _):
-            tokens, cache, remaining, pos, active = carry
-            nxt, cache = decode_fn(params, tokens, cache)
+            (tokens, cache, remaining, pos, active), stats = carry
+            nxt, cache, step_stats = decode_fn(params, tokens, cache)
             remaining = jnp.where(active, remaining - 1, remaining)
             pos = jnp.where(active, pos + 1, pos)
             finished = active & ((remaining <= 0) | (nxt == eos_id)
                                  | (pos >= max_len - 1))
-            return ((nxt, cache, remaining, pos, active & ~finished),
+            return (((nxt, cache, remaining, pos, active & ~finished),
+                     DependabilityStats.merge(stats, step_stats)),
                     (nxt, finished))
-        carry, emitted = jax.lax.scan(
-            body, (tokens, cache, remaining, pos, active),
+        (carry, stats), emitted = jax.lax.scan(
+            body, ((tokens, cache, remaining, pos, active),
+                   DependabilityStats.zero()),
             None, length=n_steps)
-        return carry, emitted
+        return carry, emitted, stats
 
-    fn = jax.jit(_window)
+    fn = jax.jit(engine_decode_window)
     _DECODE_WINDOW_CACHE[key] = (decode_fn, fn)
     return fn
 
@@ -315,6 +382,9 @@ class ThreadedSource:
 # ---------------------------------------------------------------------------
 
 
+STAGE_STAMPS = ("t_submit", "t_prefill", "t_join", "t_finish", "t_release")
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -322,12 +392,28 @@ class Request:
     max_new_tokens: int = 16
     # filled by the pipeline
     output: Optional[List[int]] = None
-    submitted_at: float = 0.0
-    finished_at: float = 0.0
-    # deterministic tick-clock counterparts of the wall timestamps (filled
-    # only when the executor has observability attached; -1 = not stamped)
+    # stage stamps on time.perf_counter(), always taken (0.0 = not reached):
+    # submitted, prefill started, joined a decode slot, last token decoded,
+    # released.  A rollback that replays or requeues a request clears the
+    # stamps it will take again.
+    t_submit: float = 0.0
+    t_prefill: float = 0.0
+    t_join: float = 0.0
+    t_finish: float = 0.0
+    t_release: float = 0.0
+    # the pump ticks of submit and finish on the executor's deterministic
+    # clock (-1 = not stamped)
     submitted_tick: int = -1
     finished_tick: int = -1
+
+    def stamps(self) -> List[float]:
+        return [getattr(self, k) for k in STAGE_STAMPS]
+
+    def clear_stamps(self, after: str) -> None:
+        """Zero every stage stamp after ``after`` (a ``STAGE_STAMPS``
+        name): the stages the request will pass again."""
+        for k in STAGE_STAMPS[STAGE_STAMPS.index(after) + 1:]:
+            setattr(self, k, 0.0)
 
     # ------------------------------------------------- transport (wire form)
     def to_doc(self) -> dict:
@@ -340,8 +426,7 @@ class Request:
             "max_new_tokens": int(self.max_new_tokens),
             "output": (None if self.output is None
                        else [int(t) for t in self.output]),
-            "submitted_at": float(self.submitted_at),
-            "finished_at": float(self.finished_at),
+            "stamps": [float(t) for t in self.stamps()],
             "submitted_tick": int(self.submitted_tick),
             "finished_tick": int(self.finished_tick),
         }
@@ -354,8 +439,8 @@ class Request:
             max_new_tokens=int(doc.get("max_new_tokens", 16)),
             output=(None if doc.get("output") is None
                     else [int(t) for t in doc["output"]]),
-            submitted_at=float(doc.get("submitted_at", 0.0)),
-            finished_at=float(doc.get("finished_at", 0.0)),
+            **dict(zip(STAGE_STAMPS, doc.get("stamps",
+                                             [0.0] * len(STAGE_STAMPS)))),
             submitted_tick=int(doc.get("submitted_tick", -1)),
             finished_tick=int(doc.get("finished_tick", -1)),
         )
@@ -365,7 +450,8 @@ class Request:
         canonical, parent-side) object — the certify upcall path."""
         self.output = (None if doc.get("output") is None
                        else [int(t) for t in doc["output"]])
-        self.finished_at = float(doc.get("finished_at", 0.0))
+        for k, t in zip(STAGE_STAMPS, doc.get("stamps", ())):
+            setattr(self, k, float(t))
         self.finished_tick = int(doc.get("finished_tick", -1))
         return self
 
@@ -465,14 +551,19 @@ class PrefillStage(Stage):
         # prompt and crash the whole engine (losing every in-flight request);
         # generation is truncated at the cache edge by the decode-stage
         # max_len guard instead
+        req.t_prefill = time.perf_counter()
         prompt = req.prompt[: max(1, ex.max_len - req.max_new_tokens)]
         if ex.cfg.recurrent is not None:
             pad = len(prompt)
         else:
             pad = -(-len(prompt) // ex.prefill_pad) * ex.prefill_pad
-        toks = jnp.asarray([prompt + [0] * (pad - len(prompt))], jnp.int32)
-        logits, cache1 = ex._prefill(ex.params, toks)
-        nxt = int(jnp.argmax(logits[0, len(prompt) - 1]))
+        with span("engine/prefill", uid=req.uid, tokens=pad):
+            toks = jnp.asarray([prompt + [0] * (pad - len(prompt))],
+                               jnp.int32)
+            logits, cache1, stats = ex._prefill(ex.params, toks)
+            ex.record_dependability(stats)
+        with span("engine/prefill_readback"):
+            nxt = int(jnp.argmax(logits[0, len(prompt) - 1]))
         return _Prefilled(req, cache1, nxt, len(prompt))
 
     def pump(self) -> bool:
@@ -555,12 +646,13 @@ class DecodeStage(Stage):
             if Channel.is_empty_token(item):
                 break
             req, n = item.req, item.prompt_len
+            req.t_join = time.perf_counter()
             ex._since_snapshot.append(req)
             if ex.tracer is not None:
                 ex.tracer.close_span(req.uid, "prefill")
                 ex.tracer.open_span(req.uid, "decode", slot=slot,
                                     prompt_len=n)
-            self.cache, self.tokens = _splice_slot(
+            self.cache, self.tokens = splice_slot(
                 self.cache, item.cache, self.tokens,
                 jnp.int32(slot), jnp.int32(item.first_token), jnp.int32(n))
             self.slot_pos[slot] = n
@@ -573,7 +665,7 @@ class DecodeStage(Stage):
             # the prefill token itself is EOS (burning the whole budget on a
             # request that already terminated would waste its slot)
             if self.slot_remaining[slot] <= 0 or item.first_token == ex.eos_id:
-                req.finished_at = time.time()
+                req.t_finish = time.perf_counter()
                 del self.active[slot]
                 self._emit(req)
         return moved
@@ -584,23 +676,28 @@ class DecodeStage(Stage):
         ex = self.ex
         if not self.active:
             return False
-        nxt, self.cache = ex._decode(ex.params, self.tokens, self.cache)
-        self.tokens = nxt
-        ex.stats.steps += 1
-        nxt_host = np.asarray(nxt)
-        done_slots = []
-        for slot, req in list(self.active.items()):
-            req.output.append(int(nxt_host[slot]))
-            self.slot_pos[slot] += 1
-            self.slot_remaining[slot] -= 1
-            ex.stats.tokens_out += 1
-            if (self.slot_remaining[slot] <= 0
-                    or int(nxt_host[slot]) == ex.eos_id
-                    or self.slot_pos[slot] >= ex.max_len - 1):
-                req.finished_at = time.time()
-                done_slots.append(slot)
-        for slot in done_slots:
-            self._emit(self.active.pop(slot))
+        with span("engine/decode_step"):
+            nxt, self.cache, stats = ex._decode(ex.params, self.tokens,
+                                                self.cache)
+            self.tokens = nxt
+            ex.record_dependability(stats)
+        with span("engine/decode_readback"):
+            nxt_host = np.asarray(nxt)
+        with span("engine/decode_replay"):
+            ex.stats.steps += 1
+            done_slots = []
+            for slot, req in list(self.active.items()):
+                req.output.append(int(nxt_host[slot]))
+                self.slot_pos[slot] += 1
+                self.slot_remaining[slot] -= 1
+                ex.stats.tokens_out += 1
+                if (self.slot_remaining[slot] <= 0
+                        or int(nxt_host[slot]) == ex.eos_id
+                        or self.slot_pos[slot] >= ex.max_len - 1):
+                    req.t_finish = time.perf_counter()
+                    done_slots.append(slot)
+            for slot in done_slots:
+                self._emit(self.active.pop(slot))
         return True
 
     def decode_window(self) -> bool:
@@ -617,28 +714,32 @@ class DecodeStage(Stage):
                                    ex.max_len)
         active_mask = np.zeros(ex.capacity, bool)
         active_mask[list(self.active)] = True
-        (tokens, cache, _, _, _), (nxt_all, fin_all) = window(
-            ex.params, self.tokens, self.cache,
-            jnp.asarray(self.slot_remaining), jnp.asarray(self.slot_pos),
-            jnp.asarray(active_mask))
-        self.tokens, self.cache = tokens, cache
-        nxt_host = np.asarray(nxt_all)            # (N, capacity)
-        fin_host = np.asarray(fin_all)
-        for i in range(ex.multi_step):
-            if not self.active:
-                break                  # trailing idle steps are not counted
-            ex.stats.steps += 1
-            done_slots = []
-            for slot, req in list(self.active.items()):
-                req.output.append(int(nxt_host[i, slot]))
-                self.slot_pos[slot] += 1
-                self.slot_remaining[slot] -= 1
-                ex.stats.tokens_out += 1
-                if fin_host[i, slot]:
-                    req.finished_at = time.time()
-                    done_slots.append(slot)
-            for slot in done_slots:
-                self._emit(self.active.pop(slot))
+        with span("engine/decode_window", slots=len(self.active)):
+            (tokens, cache, _, _, _), (nxt_all, fin_all), stats = window(
+                ex.params, self.tokens, self.cache,
+                jnp.asarray(self.slot_remaining), jnp.asarray(self.slot_pos),
+                jnp.asarray(active_mask))
+            self.tokens, self.cache = tokens, cache
+            ex.record_dependability(stats)
+        with span("engine/decode_readback"):
+            nxt_host = np.asarray(nxt_all)        # (N, capacity)
+            fin_host = np.asarray(fin_all)
+        with span("engine/decode_replay"):
+            for i in range(ex.multi_step):
+                if not self.active:
+                    break              # trailing idle steps are not counted
+                ex.stats.steps += 1
+                done_slots = []
+                for slot, req in list(self.active.items()):
+                    req.output.append(int(nxt_host[i, slot]))
+                    self.slot_pos[slot] += 1
+                    self.slot_remaining[slot] -= 1
+                    ex.stats.tokens_out += 1
+                    if fin_host[i, slot]:
+                        req.t_finish = time.perf_counter()
+                        done_slots.append(slot)
+                for slot in done_slots:
+                    self._emit(self.active.pop(slot))
         return True
 
     def decode_any(self) -> bool:
@@ -801,13 +902,8 @@ class StreamingExecutor:
             # executors over the same config compile once, not N times
             self._decode, self._prefill = compiled
         else:
-            def _step(p, t, c):
-                logits, c = model_api.decode_step(cfg, p, t, c)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), c
-
-            self._decode = jax.jit(_step)
-            self._prefill = jax.jit(
-                lambda p, t, c=None: model_api.prefill(cfg, p, t, max_len))
+            self._decode = decode_step_fn(cfg)
+            self._prefill = prefill_fn(cfg, max_len)
 
         # channels: submission is unbounded (admission control is a policy
         # above the engine); prefill channels are slot-bounded; certify/
@@ -863,7 +959,6 @@ class StreamingExecutor:
         self.storage_scrub_every = max(1, int(storage_scrub_every))
         self._storage_checks = None
         self._golden_params = None
-        self._verify_storage = None
         self._storage_alarmed = False
         if storage_scrub != "off":
             self.refresh_storage_baseline()
@@ -922,7 +1017,8 @@ class StreamingExecutor:
         """Re-checksum after a legitimate mutation — the running 'expected'
         fingerprint every later scrub compares against."""
         if self.state_scrub != "off":
-            self._expected_check = _state_checksums(self._device_state())
+            with span("engine/state_refresh"):
+                self._expected_check = state_checksums(self._device_state())
 
     def scrub_decode_state(self) -> bool:
         """Verify the live decode state against the post-mutation checksum;
@@ -931,7 +1027,7 @@ class StreamingExecutor:
         transient site no weight scrub can see."""
         if self._expected_check is None:
             return True
-        fresh = _state_checksums(self._device_state())
+        fresh = state_checksums(self._device_state())
         clean = _checks_equal(fresh, self._expected_check)
         # emit_events=False: _scrub_and_recover emits the (site-attributed)
         # detection event itself — one detection, one event
@@ -958,7 +1054,8 @@ class StreamingExecutor:
         if self.state_scrub == "rollback" and self._snapshot is not None:
             t0 = time.perf_counter()
             try:
-                event["steps_replayed"] = self.restore_snapshot()
+                with span("engine/rollback", site="decode_state"):
+                    event["steps_replayed"] = self.restore_snapshot()
                 event["recovered"] = True
                 event["seconds"] = time.perf_counter() - t0
                 self.record_dependability({"faults_recovered": jnp.int32(1)})
@@ -985,12 +1082,8 @@ class StreamingExecutor:
         recompute the deploy-time checksums and retain the params as the
         rollback target.  Called at construction and by intentional weight
         swaps (rolling deploys); never implicitly by ``reset``."""
-        from repro.core import abft as abft_mod
-        if self._verify_storage is None:
-            self._verify_storage = jax.jit(abft_mod.verify_storage)
-            self._storage_checksums = jax.jit(abft_mod.storage_checksums)
         self._golden_params = self.params
-        self._storage_checks = self._storage_checksums(self.params)
+        self._storage_checks = _storage_checksums(self.params)
         self._storage_alarmed = False
 
     def scrub_storage(self) -> bool:
@@ -999,7 +1092,7 @@ class StreamingExecutor:
         the dependability rollup."""
         if self._storage_checks is None:
             return True
-        ok = self._verify_storage(self.params, self._storage_checks)
+        ok = storage_verify(self.params, self._storage_checks)
         clean = all(bool(x) for x in jax.tree_util.tree_leaves(ok))
         self.record_dependability({
             "faults_detected": jnp.int32(0 if clean else 1),
@@ -1023,7 +1116,8 @@ class StreamingExecutor:
                                 detail={"check": "storage_scrub"})
         if self.storage_scrub == "rollback":
             t0 = time.perf_counter()
-            self.params = self._golden_params
+            with span("engine/rollback", site="weights"):
+                self.params = self._golden_params
             event["recovered"] = True
             event["seconds"] = time.perf_counter() - t0
             self.record_dependability({"faults_recovered": jnp.int32(1)})
@@ -1090,7 +1184,8 @@ class StreamingExecutor:
 
     # ------------------------------------------------------------- driving
     def submit(self, req: Request):
-        req.submitted_at = time.time()
+        req.t_submit = time.perf_counter()
+        req.clear_stamps("t_submit")
         req.submitted_tick = self.tick
         self.submit_ch.items.append(req)
         if self.tracer is not None:
@@ -1155,16 +1250,20 @@ class StreamingExecutor:
         # mutation is an SEU, and under "rollback" we restart from the
         # last verified snapshot instead of decoding from corrupted state
         if self.state_scrub != "off" and self.decode.active:
-            self._scrub_and_recover()
+            with span("engine/state_scrub"):
+                self._scrub_and_recover()
         # storage scrub on its own cadence, before any stage reads weights
         # this cycle: detect mode runs every pump (bounded detection
         # latency), rollback mode amortizes over storage_scrub_every ticks
         if self.storage_scrub != "off" \
                 and self.tick % self.storage_scrub_every == 0:
-            self._storage_scrub_and_recover()
-        self.admit.pump()
+            with span("engine/storage_scrub"):
+                self._storage_scrub_and_recover()
+        with span("engine/admit"):
+            self.admit.pump()
         self.prefill.pump()
-        self.decode.join()
+        with span("engine/join"):
+            self.decode.join()
         if self.decode.active:
             # cadence by steps-since-snapshot (≡ steps % snapshot_every for
             # per-step decode; windowed decode advances steps by up to N per
@@ -1172,14 +1271,29 @@ class StreamingExecutor:
             if (self._snapshot is None
                     or self.stats.steps - self._snapshot_step
                     >= self.snapshot_every):
-                self._take_snapshot()
+                with span("engine/snapshot"):
+                    self._take_snapshot()
             self.decode.decode_any()
         self._refresh_state_check()
         # certify/release pump AFTER the decode state is settled: a certify
         # hook may re-enter the executor (fleet recalls, resets, replays)
-        self.certifier.pump()
+        with span("engine/certify"):
+            self.certifier.pump()
+        with span("engine/release"):
+            released = self._release()
+        return released
+
+    def _release(self) -> List[Request]:
+        """Collect what cleared the release stage: stamp it, log its stage
+        stamps (``obs.trace.RELEASED``), and feed the tracer and metrics."""
         self.release.pump()
         released = self.release.collect()
+        now = time.perf_counter()
+        for req in released:
+            req.t_release = now
+            obs_trace.RELEASED.append(
+                (req.uid, len(req.prompt), len(req.output or ()),
+                 *req.stamps()))
         if self.tracer is not None:
             for req in released:
                 self.tracer.instant("release", stage="release", uid=req.uid,
@@ -1251,7 +1365,7 @@ class StreamingExecutor:
             "tokens_out": self.stats.tokens_out,
             # golden-snapshot integrity: checksummed at capture so a later
             # restore can refuse a snapshot that was itself struck
-            "check": (_state_checksums(
+            "check": (state_checksums(
                 {"cache": d.cache, "tokens": d.tokens})
                 if self.state_scrub != "off" else None),
         }
@@ -1276,7 +1390,7 @@ class StreamingExecutor:
             raise RuntimeError("no snapshot taken yet")
         snap = self._snapshot
         if snap["check"] is not None:
-            fresh = _state_checksums(
+            fresh = state_checksums(
                 {"cache": snap["cache"], "tokens": snap["tokens"]})
             if not _checks_equal(fresh, snap["check"]):
                 raise RuntimeError(
@@ -1302,7 +1416,7 @@ class StreamingExecutor:
         tr = self.tracer
         for s, req in d.active.items():
             req.output = list(snap["outputs"][s])
-            req.finished_at = 0.0
+            req.clear_stamps("t_join")
             req.finished_tick = -1
             if tr is not None:
                 # resurrected: back in decode; a suspect copy may have
@@ -1312,7 +1426,7 @@ class StreamingExecutor:
                 tr.open_span(req.uid, "decode", slot=s, replayed=True)
         for req in reversed(self._since_snapshot):
             req.output = None
-            req.finished_at = 0.0
+            req.clear_stamps("t_submit")
             req.finished_tick = -1
             self.submit_ch.items.appendleft(req)
             if tr is not None:

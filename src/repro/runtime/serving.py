@@ -312,8 +312,10 @@ class Engine:
         """Host-side dependability summary: detection counters + the
         replay/snapshot state a campaign needs to judge recovery cost."""
         from repro.core.dependability import DependabilityStats
+        from repro.obs.trace import span
         ex = self._ex
-        out = DependabilityStats.to_host(ex.dependability)
+        with span("engine/report"):
+            out = DependabilityStats.to_host(ex.dependability)
         out.update(steps=ex.stats.steps, replays=ex.stats.replays,
                    tokens_out=ex.stats.tokens_out,
                    snapshot_every=ex.snapshot_every,

@@ -3,6 +3,10 @@ bit-identity with mid-decode joins across model families, per-stage fault
 injection, the certify release gate, and the pad-and-step drain barrier."""
 from __future__ import annotations
 
+import collections
+import contextlib
+import dataclasses
+import re
 import threading
 import time
 
@@ -244,13 +248,13 @@ def test_certify_hook_withholds_and_releases(smollm):
         released += eng.step()
     assert released == []
     assert sorted(r.uid for r in held) == [0, 1]
-    assert all(r.finished_at > 0 for r in held)   # finished, just not released
+    assert all(r.t_finish > 0 for r in held)   # finished, just not released
 
     eng.certify = lambda req: True
     eng.reset()
     for r in reqs:
         r.output = None
-        r.finished_at = 0.0
+        r.t_finish = 0.0
         eng.submit(r)
     released = []
     while eng.executor.busy():
@@ -274,7 +278,7 @@ def test_fleet_release_gate_lives_in_certify_stage(smollm):
         for _ in range(10):
             fleet.tick()
         # finished but withheld: certification (scrub cadence) never came
-        assert req.finished_at > 0
+        assert req.t_finish > 0
         assert req.uid not in fleet.released
         assert any(any(q.uid == req.uid for q in r.uncertified)
                    for r in fleet.replicas)
@@ -334,7 +338,7 @@ def test_prefill_eos_finishes_at_admission(smollm):
     while eng.executor.busy():
         released += eng.step()
     assert req.output == [t0]                 # terminated at admission
-    assert req.finished_at > 0
+    assert req.t_finish > 0
     assert sorted(r.uid for r in released) == [0, 1]
     assert len(other.output) >= 1             # neighbor unaffected
 
@@ -361,7 +365,7 @@ def test_decode_truncates_at_max_len(smollm, multi_step):
     assert len(req.output) == want_len
     assert req.output == greedy_reference(cfg, params, eff, want_len,
                                           max_len=max_len)
-    assert req.finished_at > 0
+    assert req.t_finish > 0
 
 
 def test_multi_step_windows_are_bit_identical(family):
@@ -550,3 +554,223 @@ def test_channel_cooperative_spsc_threaded_no_loss():
     tp.join(timeout=30)
     tc.join(timeout=30)
     assert got == list(range(n))
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans, program names, stage stamps, FFN check counters
+# ---------------------------------------------------------------------------
+
+STATE_MAP = {"default": "none", "rules": [
+    {"pattern": "kv_cache", "policy": "ckpt"},
+    {"pattern": "decode_state", "policy": "ckpt"},
+    {"pattern": "weights", "policy": "ckpt"}]}
+
+FFN_MAP = {"default": "none", "rules": [{"pattern": "ffn.*", "policy": "abft"}]}
+
+# one pump's top-level spans, in order (``*`` = once per prefilled request)
+PUMP = re.compile(
+    r"^(state_scrub )?(storage_scrub )?admit (prefill prefill_readback )*"
+    r"join (snapshot )?((decode_window|decode_step) decode_readback "
+    r"decode_replay )?(state_refresh )?certify release $")
+
+
+class SpanRecorder:
+    """Stands in for ``obs.span``: records (pump, depth, name) of every
+    span the engine opens."""
+
+    def __init__(self):
+        self.events, self.depth, self.pump = [], 0, 0
+
+    @contextlib.contextmanager
+    def __call__(self, name, **args):
+        self.events.append((self.pump, self.depth, name))
+        self.depth += 1
+        try:
+            yield
+        finally:
+            self.depth -= 1
+
+    def pumps(self):
+        """Per pump: the names of its top-level spans, prefix dropped."""
+        out = collections.defaultdict(str)
+        for pump, depth, name in self.events:
+            if depth == 0:
+                out[pump] += name.split("/", 1)[1] + " "
+        return [out[p] for p in sorted(out)]
+
+
+def _drive(eng, rec, reqs, strike_after=None):
+    for r in reqs:
+        eng.submit(r)
+    while eng.executor.busy():
+        rec.pump += 1
+        eng.step()
+        if rec.pump == strike_after:
+            eng.strike("decode_state", fi.flip_one_bit, jax.random.key(3))
+
+
+@pytest.mark.parametrize("policy_map", [STATE_MAP, None])
+def test_engine_spans_in_pump_order(smollm, monkeypatch, policy_map):
+    """Every pump opens its boundary spans in stage order, one level deep;
+    a rollback nests inside the scrub that ran it.  Scrub and refresh spans
+    appear only where the policy map turns the scrubs on; snapshots keep
+    their cadence either way."""
+    cfg, params = smollm
+    rec = SpanRecorder()
+    monkeypatch.setattr(df, "span", rec)
+    eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                 multi_step=2, snapshot_every=2, policy_map=policy_map)
+    reqs = [Request(uid=i, prompt=[3 + i, 7, 1], max_new_tokens=7)
+            for i in range(3)]
+    _drive(eng, rec, reqs, strike_after=1 if policy_map else None)
+    names = {n for _, _, n in rec.events}
+    assert all(n.startswith("engine/") for n in names)
+    for pump in rec.pumps():
+        assert PUMP.match(pump), pump
+    nested = [(d, n) for _, d, n in rec.events if d > 0]
+    scrubs = {"engine/state_scrub", "engine/state_refresh",
+              "engine/storage_scrub", "engine/rollback"}
+    assert "engine/snapshot" in names
+    assert sum(n == "engine/prefill" for _, _, n in rec.events) == 3
+    if policy_map is None:
+        assert not names & scrubs and not nested
+        return
+    assert scrubs <= names
+    # the struck state was rolled back from inside the pre-decode scrub,
+    # which re-checksums the restored state
+    assert nested == [(1, "engine/rollback"), (2, "engine/state_refresh")]
+    at = [i for i, e in enumerate(rec.events) if e[2] == "engine/rollback"][0]
+    assert rec.events[at - 1][1:] == (0, "engine/state_scrub")
+    # the storage scrub runs on its own cadence (every snapshot_every pumps)
+    ticks = {p for p, _, n in rec.events if n == "engine/storage_scrub"}
+    assert ticks == {p for p in range(1, rec.pump + 1) if p % 2 == 0}
+
+
+def test_engine_programs_have_stable_module_names(smollm):
+    """Each program the engine runs lowers to a module named after it, as a
+    profile shows it."""
+    cfg, params = smollm
+    eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                 multi_step=2, policy_map=STATE_MAP)
+    ex = eng.executor
+    toks = jnp.zeros((1, 8), jnp.int32)
+    one = ex._prefill(params, toks)[1]
+    vec = jnp.zeros((2,), jnp.int32)
+    window = df._decode_window_fn(ex._decode, 2, -1, 96)
+    lowered = {
+        "jit_engine_prefill": ex._prefill.lower(params, toks),
+        "jit_engine_decode_step": ex._decode.lower(params, vec, eng.cache),
+        "jit_engine_decode_window": window.lower(
+            params, vec, eng.cache, vec, vec, jnp.ones((2,), bool)),
+        "jit_splice_slot": df.splice_slot.lower(
+            eng.cache, one, vec, jnp.int32(0), jnp.int32(1), jnp.int32(3)),
+        "jit_state_checksums": df.state_checksums.lower(
+            ex._device_state()),
+        "jit_storage_verify": df.storage_verify.lower(
+            params, ex._storage_checks),
+        "jit_storage_checksums": df._storage_checksums.lower(params),
+    }
+    for name, low in lowered.items():
+        assert f"module @{name} " in low.as_text(), name
+
+
+def test_stage_stamps_monotone_across_rollback(smollm):
+    """submit <= prefill <= join <= finish <= release for every released
+    request, with a snapshot rollback mid-run; the release log holds each
+    release's stamps."""
+    from repro.obs import trace as obs_trace
+    cfg, params = smollm
+    eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                 snapshot_every=2, state_scrub="rollback")
+    reqs = [Request(uid=100 + i, prompt=[2 + i, 5], max_new_tokens=3 + 2 * i)
+            for i in range(4)]
+    for r in reqs:
+        eng.submit(r)
+    released = []
+    for pump in range(200):
+        if not eng.executor.busy():
+            break
+        released += eng.step()
+        if pump == 2:
+            eng.strike("decode_state", fi.flip_one_bit, jax.random.key(5))
+    assert eng.drain_state_events()[0]["recovered"]
+    assert sorted(r.uid for r in released) == [100, 101, 102, 103]
+    for r in released:
+        st = r.stamps()
+        assert 0 < st[0] and st == sorted(st), (r.uid, st)
+    log = {row[0]: row for row in list(obs_trace.RELEASED)[-len(released):]}
+    for r in released:
+        assert log[r.uid][1:3] == (len(r.prompt), len(r.output))
+        assert list(log[r.uid][3:]) == r.stamps()
+
+
+def test_request_stamps_restart_on_resubmit():
+    req = Request(uid=1, prompt=[1], max_new_tokens=2, t_submit=1.0,
+                  t_prefill=2.0, t_join=3.0, t_finish=4.0, t_release=5.0)
+    req.clear_stamps("t_join")
+    assert req.stamps() == [1.0, 2.0, 3.0, 0.0, 0.0]
+    req.clear_stamps("t_submit")
+    assert req.stamps() == [1.0, 0.0, 0.0, 0.0, 0.0]
+
+
+@pytest.fixture(scope="module")
+def smollm_w8a8():
+    cfg = dataclasses.replace(reduced(registry.get("smollm-135m")),
+                              quant="w8a8_ffn")
+    return cfg, model_api.init_params(cfg, jax.random.key(1))
+
+
+@pytest.mark.parametrize("multi_step", [1, 3])
+def test_ffn_checks_reach_the_engine(smollm_w8a8, monkeypatch, multi_step):
+    """A clean run counts 3 FFN checks a layer for every decode step the
+    device runs (a window runs all its steps, idle trailing ones included)
+    and for each of prefill's two FFN passes, and detects nothing."""
+    cfg, params = smollm_w8a8
+    rec = SpanRecorder()
+    monkeypatch.setattr(df, "span", rec)
+    eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                 multi_step=multi_step, policy_map=FFN_MAP)
+    reqs = [Request(uid=i, prompt=[4 + i, 9, 2], max_new_tokens=4 + 3 * i)
+            for i in range(3)]
+    _drive(eng, rec, reqs)
+    dispatches = sum(n in ("engine/decode_window", "engine/decode_step")
+                     for _, _, n in rec.events)
+    prefills = sum(n == "engine/prefill" for _, _, n in rec.events)
+    rep = eng.dependability_report()
+    assert prefills == 3 and dispatches > 0
+    assert rep["checks_run"] == 3 * cfg.n_layers * (
+        multi_step * dispatches + 2 * prefills)
+    assert rep["faults_detected"] == rep["faults_corrected"] == 0
+
+
+def test_ffn_accumulator_fault_is_counted_and_corrected(smollm_w8a8,
+                                                        monkeypatch):
+    """A fault planted in every protected FFN accumulator (through
+    ``dependable_matmul_acc``'s ``inject``) is detected and corrected in
+    place, and the engine counts both; the tokens match a clean run."""
+    from repro.core import dependability as dep
+    cfg, params = smollm_w8a8
+
+    def serve():
+        eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                     multi_step=2, policy_map=FFN_MAP)
+        reqs = [Request(uid=i, prompt=[6 + i, 1], max_new_tokens=5)
+                for i in range(2)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        return [r.output for r in reqs], eng.dependability_report()
+
+    clean, _ = serve()
+    acc_fn = dep.dependable_matmul_acc
+
+    def struck(policy, x_q, w_q, **kw):
+        return acc_fn(policy, x_q, w_q,
+                      inject=lambda a: a.at[0, 0].add(1 << 12), **kw)
+
+    monkeypatch.setattr(dep, "dependable_matmul_acc", struck)
+    outputs, rep = serve()
+    assert rep["faults_detected"] > 0
+    assert rep["faults_corrected"] == rep["faults_detected"]
+    assert rep["faults_detected"] == rep["checks_run"]
+    assert outputs == clean

@@ -460,8 +460,10 @@ def test_request_wire_doc_round_trip():
     finished = Request.from_doc(req.to_doc())
     finished.output = [9, 8]
     finished.finished_tick = 4
+    finished.t_submit, finished.t_finish = 1.5, 2.5
     req.sync_from_doc(finished.to_doc())
     assert req.output == [9, 8] and req.finished_tick == 4
+    assert req.stamps() == [1.5, 0.0, 0.0, 2.5, 0.0]
 
 
 @pytest.mark.parametrize("arch", [

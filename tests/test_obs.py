@@ -446,3 +446,24 @@ def test_fleet_metrics_histograms_are_streaming():
         m.observe_release(i % 128, 1)
     assert m.latencies.count == 50_000
     assert m.p50_ticks <= m.p99_ticks <= m.latencies.max
+
+
+def test_profiler_span_lands_on_the_host_line(tmp_path):
+    """``obs.span`` writes a host event into the profiler's trace, under
+    its own name, with its arguments as the event's stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.obs import trace as obs_trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("engine/test_span", uid=7):
+            jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    found = [dict(e.stats) for p in ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events
+             if e.name == "engine/test_span"]
+    assert found == [{"uid": 7}]

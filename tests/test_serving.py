@@ -94,12 +94,12 @@ def test_snapshot_restore_round_trips_stats_and_finished_requests(served):
     eng, reqs = fresh()
     eng.step()
     eng.step()          # req 1 (max_new=3) finishes here, after the snapshot
-    assert reqs[1].finished_at > 0
+    assert reqs[1].t_finish > 0
     eng.tokens = eng.tokens.at[0].set(123)        # SEU in decode state
     eng.restore_snapshot()
     # the finished request was resurrected — its post-snapshot tokens were
     # produced after the corruption window and must be re-decoded
-    assert reqs[1].finished_at == 0.0
+    assert reqs[1].t_finish == 0.0
     eng.run()
     assert [list(r.output) for r in reqs] == golden
     assert eng.stats.steps == clean_stats.steps
@@ -123,7 +123,7 @@ def test_restore_requeues_requests_admitted_after_snapshot(served):
         eng.submit(r)
     for _ in range(3):
         eng.step()      # snapshot@0; req0 finishes; req1 admitted at step 2
-    assert reqs[0].finished_at > 0 and reqs[1].output is not None
+    assert reqs[0].t_finish > 0 and reqs[1].output is not None
     eng.tokens = eng.tokens.at[0].set(77)
     eng.restore_snapshot()
     assert reqs[1] in eng.queue                   # requeued, prefill redone
