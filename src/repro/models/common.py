@@ -175,8 +175,18 @@ def decode_attention(
     cur_len: jax.Array,       # (B,) or scalar — number of valid cache slots
     k_scale: Optional[jax.Array] = None,   # (B, T, KV) int8-KV scales
     v_scale: Optional[jax.Array] = None,
+    new: Optional[tuple] = None,
 ) -> jax.Array:
-    """Single-token attention over a (ring-buffered) KV cache."""
+    """Single-token attention over a (ring-buffered) KV cache.
+
+    ``new = (slot, k, v[, k_scale, v_scale])`` — slot (B,), rows (B, KV, hd)
+    in the cache's dtype, scales (B, KV) — is the token's own K/V, attended
+    as if it were already written at ``slot``: its score replaces the
+    page's at that slot (whatever the page holds there, e.g. the oldest
+    entry of a full ring buffer, is masked out) and its value term is added
+    after the page's.  The page is only read, so the caller writes the rows
+    afterwards and the result equals write-then-attend up to the order of
+    the value sum."""
     B, T, KV, hd = k_cache.shape
     H = q.shape[2]
     G = H // KV
@@ -190,23 +200,46 @@ def decode_attention(
         q_s = jnp.maximum(q_s, 1e-8) / 127.0
         q_q = jnp.clip(jnp.round(qg.astype(jnp.float32) / q_s[..., None]),
                        -127, 127).astype(jnp.int8)
-        s32 = jnp.einsum("bkgh,btkh->bkgt", q_q, k_cache,
-                         preferred_element_type=jnp.int32)
-        ks_t = jnp.moveaxis(k_scale, 1, 2)[:, :, None, :]       # (B, KV, 1, T)
-        s = s32.astype(jnp.float32) * q_s[..., None] * ks_t * scale
-        v_cache = (v_cache.astype(jnp.float32)
-                   * v_scale[..., None]).astype(q.dtype)
+
+        def scores(k, ks):                      # (B, t, KV, hd), (B, t, KV)
+            s32 = jnp.einsum("bkgh,btkh->bkgt", q_q, k,
+                             preferred_element_type=jnp.int32)
+            ks_t = jnp.moveaxis(ks, 1, 2)[:, :, None, :]       # (B, KV, 1, t)
+            return s32.astype(jnp.float32) * q_s[..., None] * ks_t * scale
+
+        def values(v, vs):
+            return (v.astype(jnp.float32) * vs[..., None]).astype(q.dtype)
     else:
         # operands stay in the cache dtype (bf16): no f32 copy of the (T,·)
         # cache pages — accumulation is f32 via preferred_element_type
         # (decode is cache-read bound; an astype would double the traffic)
-        s = jnp.einsum("bkgh,btkh->bkgt", qg, k_cache,
-                       preferred_element_type=jnp.float32) * scale
+        def scores(k, _):
+            return jnp.einsum("bkgh,btkh->bkgt", qg, k,
+                              preferred_element_type=jnp.float32) * scale
+
+        def values(v, _):
+            return v
+    s = scores(k_cache, k_scale)
+    if new is not None:
+        slot, k_new, v_new, *row_scales = new
+        ks_new, vs_new = row_scales or (None, None)
+        at = (jnp.arange(T)[None, :] == slot[:, None])[:, None, None, :]
+        s_new = scores(k_new[:, None],
+                       None if ks_new is None else ks_new[:, None])
+        s = jnp.where(at, s_new, s)
     valid = jnp.arange(T)[None, :] < jnp.broadcast_to(jnp.atleast_1d(cur_len), (B,))[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgt,btkh->bkgh", p.astype(v_cache.dtype), v_cache,
+    v_page = values(v_cache, v_scale)
+    p_page = p if new is None else jnp.where(at, 0.0, p)
+    out = jnp.einsum("bkgt,btkh->bkgh", p_page.astype(v_page.dtype), v_page,
                      preferred_element_type=jnp.float32)
+    if new is not None:
+        v_row = values(v_new[:, None], None if vs_new is None
+                       else vs_new[:, None])[:, 0]              # (B, KV, hd)
+        p_new = jnp.sum(jnp.where(at, p, 0.0), axis=-1)        # (B, KV, G)
+        out = out + (p_new.astype(v_row.dtype).astype(jnp.float32)[..., None]
+                     * v_row.astype(jnp.float32)[:, :, None, :])
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 

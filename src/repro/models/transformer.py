@@ -734,18 +734,18 @@ def init_cache(cfg: ArchConfig, B: int, max_len: int, dtype=None) -> KVCache:
                    jnp.zeros((B,), jnp.int32))
 
 
-def _block_decode(cfg: ArchConfig, bp, x, k_cache, v_cache, pos, T,
-                  ks=None, vs=None):
+def _block_decode(cfg: ArchConfig, bp, x, pages, pos, T):
     """One block's single-token attention. x: (B, 1, d), pos: (B,) per-row
-    positions (ragged continuous batching). ks/vs: int8-KV scale pages
-    (B, T, KV) when cfg.quant_kv. Returns new x, cache pages (+ scales)."""
+    positions (ragged continuous batching), pages: this layer's cache pages
+    (k, v) — plus the (B, T, KV) scale pages (k_s, v_s) when cfg.quant_kv —
+    which are read, not written.  Returns new x and the token's cache rows,
+    (B, KV, hd) each (+ (B, KV) scales), for the caller to write at
+    ``pos % T``."""
     with jax.named_scope("attention"):
-        return _block_decode_attention(cfg, bp, x, k_cache, v_cache, pos, T,
-                                       ks, vs)
+        return _block_decode_attention(cfg, bp, x, pages, pos, T)
 
 
-def _block_decode_attention(cfg: ArchConfig, bp, x, k_cache, v_cache, pos,
-                            T, ks, vs):
+def _block_decode_attention(cfg: ArchConfig, bp, x, pages, pos, T):
     B = x.shape[0]
     hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
@@ -763,62 +763,34 @@ def _block_decode_attention(cfg: ArchConfig, bp, x, k_cache, v_cache, pos,
     q = common.apply_rope(q, pos_b, cfg.rope_theta)
     k = common.apply_rope(k, pos_b, cfg.rope_theta)
 
-    slot = pos % T                                   # (B,) ring-buffer slots
-    rows = jnp.arange(B)
-    valid = jnp.minimum(pos + 1, T)                  # (B,)
-    if ks is not None:                               # int8 KV cache
-        with jax.named_scope("kv_update"):
+    with jax.named_scope("kv_update"):
+        if cfg.quant_kv:
             k_q, k_sc = _quantize_kv_rows(k[:, 0])   # (B, KV, hd), (B, KV)
             v_q, v_sc = _quantize_kv_rows(v[:, 0])
-            k_cache = k_cache.at[rows, slot].set(k_q)
-            v_cache = v_cache.at[rows, slot].set(v_q)
-            ks = ks.at[rows, slot].set(k_sc)
-            vs = vs.at[rows, slot].set(v_sc)
-        o = common.decode_attention(q, k_cache, v_cache, valid,
-                                    k_scale=ks, v_scale=vs)
-        out = (x + (o.reshape(B, 1, H * hd) @ _w(cfg, bp["wo"])).astype(x.dtype))
-        return out, k_cache, v_cache, ks, vs
-    with jax.named_scope("kv_update"):
-        k_cache = k_cache.at[rows, slot].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, slot].set(v[:, 0].astype(v_cache.dtype))
-    o = common.decode_attention(q, k_cache, v_cache, valid)
+            rows = (k_q, v_q, k_sc, v_sc)
+        else:
+            rows = (k[:, 0].astype(pages[0].dtype),
+                    v[:, 0].astype(pages[1].dtype))
+    valid = jnp.minimum(pos + 1, T)                  # (B,)
+    o = common.decode_attention(q, *pages[:2], valid, *pages[2:],
+                                new=(pos % T, *rows))
     x = x + (o.reshape(B, 1, H * hd) @ _w(cfg, bp["wo"])).astype(x.dtype)
-    return x, k_cache, v_cache, None, None
+    return x, rows
 
 
-def _block_decode_inplace(cfg: ArchConfig, bp, x, k_all, v_all, li, pos, T):
-    """Like _block_decode, but scatters the new token row DIRECTLY into the
-    full (L, B, T, KV, hd) cache buffer at [li] — a B-row write instead of a
-    (B, T, ·) page-out — then reads the layer page once for attention (the
-    irreducible cache read)."""
-    B = x.shape[0]
-    hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
-    h = common.rms_norm(x, bp["ln1"], cfg.norm_eps)
-    q = (h @ _w(cfg, bp["wq"])).reshape(B, 1, H, hd)
-    k = (h @ _w(cfg, bp["wk"])).reshape(B, 1, KV, hd)
-    v = (h @ _w(cfg, bp["wv"])).reshape(B, 1, KV, hd)
-    if cfg.use_bias:
-        q = q + _w(cfg, bp["bq"]).reshape(1, 1, H, hd)
-        k = k + _w(cfg, bp["bk"]).reshape(1, 1, KV, hd)
-        v = v + _w(cfg, bp["bv"]).reshape(1, 1, KV, hd)
-    if cfg.qk_norm:
-        q = common.rms_norm(q, bp["q_norm"], cfg.norm_eps)
-        k = common.rms_norm(k, bp["k_norm"], cfg.norm_eps)
-    pos_b = pos[:, None]
-    q = common.apply_rope(q, pos_b, cfg.rope_theta)
-    k = common.apply_rope(k, pos_b, cfg.rope_theta)
-
-    slot = pos % T                                   # (B,) ring-buffer slots
-    rows = jnp.arange(B)
-    li_b = jnp.broadcast_to(li, (B,))
-    k_all = k_all.at[li_b, rows, slot].set(k[:, 0].astype(k_all.dtype))
-    v_all = v_all.at[li_b, rows, slot].set(v[:, 0].astype(v_all.dtype))
-    kc = jax.lax.dynamic_index_in_dim(k_all, li, 0, keepdims=False)
-    vc = jax.lax.dynamic_index_in_dim(v_all, li, 0, keepdims=False)
-    valid = jnp.minimum(pos + 1, T)
-    o = common.decode_attention(q, kc, vc, valid)
-    x = x + (o.reshape(B, 1, H * hd) @ _w(cfg, bp["wo"])).astype(x.dtype)
-    return x, k_all, v_all
+def _write_rows(arrays, rows, slot):
+    """Write row b of every layer, ``rows[i][:, b]``, at ``[:, b, slot[b]]``
+    of ``arrays[i]`` (L, B, T, ...): one dynamic-update-slice per batch row.
+    Unrolled, each write takes the cache in the layout its readers want; a
+    scatter, or the same writes in a loop, picks a layout of its own, and
+    XLA then converts the whole cache to it and back every step."""
+    arrays = tuple(arrays)
+    for b in range(slot.shape[0]):
+        arrays = tuple(
+            jax.lax.dynamic_update_slice(
+                a, r[:, b, None, None], (0, b, slot[b]) + (0,) * (a.ndim - 3))
+            for a, r in zip(arrays, rows))
+    return arrays
 
 
 def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
@@ -826,12 +798,13 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
                 embed: Optional[jax.Array] = None):
     """token: (B,) int32 (or embed (B, d)). Returns (logits (B, V), cache).
 
-    Cache pages ride the layer scan as xs/ys: the per-layer (B, T, ·) page
-    gets a one-row scatter and is emitted as a ys — XLA's loop-residual
-    stacking performs the page write as an in-place dynamic-update-slice
-    under donation.  (A carried-full-buffer variant with a dynamic layer
-    index was measured 2.8× WORSE: scatter through a traced layer index on
-    the (L,·) buffer lowers to full-buffer masked selects per layer.)
+    Attend, then write: the layer scan reads each layer's cache page (a
+    dynamic index into the stacked cache, which it never re-stacks),
+    attends over it together with the token's own K/V
+    (``common.decode_attention``'s ``new``), and emits only the token's
+    rows.  After the scan ``_write_rows`` writes them at ``[:, b, pos %
+    T]``, in place when the caller donates the cache (the engine's decode
+    programs do).
 
     The dense FFN checks' stats, summed over layers, go to an open
     ``collect_stats`` sink.
@@ -844,19 +817,17 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
         x = params["embed"][token][:, None, :].astype(_cdt(cfg))
     pos = cache.length
     T = cache.k.shape[2]
-
-    n_dense = cfg.moe.n_dense_layers if cfg.moe else 0
-    qkv_cache = cfg.quant_kv
+    arrays = ((cache.k, cache.v, cache.k_s, cache.v_s) if cfg.quant_kv
+              else (cache.k, cache.v))
 
     def make_body(moe: bool):
         def body(carry, layer):
             x, stats = carry
-            if qkv_cache:
-                bp, kc, vc, ksp, vsp = layer
-            else:
-                (bp, kc, vc), ksp, vsp = layer, None, None
-            x, kc, vc, ksp, vsp = _block_decode(cfg, bp, x, kc, vc, pos, T,
-                                                ksp, vsp)
+            bp, li = layer
+            pages = tuple(jax.lax.dynamic_index_in_dim(a, li, 0,
+                                                       keepdims=False)
+                          for a in arrays)
+            x, rows = _block_decode(cfg, bp, x, pages, pos, T)
             if moe:
                 if ctx is not None:
                     x, _, _ = _moe_ffn(cfg, bp, x, ctx)
@@ -864,38 +835,21 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
                     x, _, _ = _moe_ffn_single(cfg, bp, x)
             else:
                 x, stats = _dense_ffn(cfg, bp, x, stats)
-            return (x, stats), ((kc, vc, ksp, vsp) if qkv_cache else (kc, vc))
+            return (x, stats), rows
         return body
 
-    def xs_for(blocks, lo, hi):
-        if qkv_cache:
-            return (blocks, cache.k[lo:hi], cache.v[lo:hi],
-                    cache.k_s[lo:hi], cache.v_s[lo:hi])
-        return (blocks, cache.k[lo:hi], cache.v[lo:hi])
-
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-
-    def collect(ys):
-        if qkv_cache:
-            kc, vc, ksp, vsp = ys
-            new_ks.append(ksp)
-            new_vs.append(vsp)
-        else:
-            kc, vc = ys
-        new_k.append(kc)
-        new_v.append(vc)
-
+    parts = []
     carry = (x, DependabilityStats.zero())
-    if params.get("dense_blocks") is not None:
-        nd = jax.tree_util.tree_leaves(params["dense_blocks"])[0].shape[0]
-        carry, ys = jax.lax.scan(make_body(False), carry,
-                                 xs_for(params["dense_blocks"], 0, nd))
-        collect(ys)
-    if params.get("moe_blocks") is not None:
-        carry, ys = jax.lax.scan(make_body(True), carry,
-                                 xs_for(params["moe_blocks"], n_dense,
-                                        cfg.n_layers))
-        collect(ys)
+    lo = 0
+    for moe, blocks in ((False, params.get("dense_blocks")),
+                        (True, params.get("moe_blocks"))):
+        if blocks is None:
+            continue
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        carry, rows = jax.lax.scan(make_body(moe), carry,
+                                   (blocks, jnp.arange(lo, lo + n)))
+        parts.append(rows)
+        lo += n
     x, stats = carry
     collect_stats.deposit(stats)
 
@@ -903,13 +857,14 @@ def decode_step(cfg: ArchConfig, params, token: jax.Array, cache: KVCache,
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = (x @ head.astype(x.dtype)).reshape(B, -1)
 
-    def cat(parts):
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-
-    return logits, KVCache(
-        cat(new_k), cat(new_v), cache.length + 1,
-        cat(new_ks) if qkv_cache else None,
-        cat(new_vs) if qkv_cache else None)
+    # rows: (L, B, KV, hd) / (L, B, KV) — the token's row of every layer
+    rows = [p[0] if len(parts) == 1 else jnp.concatenate(p)
+            for p in zip(*parts)]
+    slot = pos % T
+    with jax.named_scope("kv_update"):
+        arrays = _write_rows(arrays, rows, slot)
+    return logits, KVCache(arrays[0], arrays[1], cache.length + 1,
+                           *arrays[2:])
 
 
 def prefill(cfg: ArchConfig, params, tokens: jax.Array, max_len: int,

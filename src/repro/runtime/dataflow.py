@@ -85,6 +85,7 @@ See docs/observability.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -104,8 +105,14 @@ from repro.obs.trace import span
 # Every program the engine runs is a named function, so its XLA module
 # reads ``jit_<name>`` in a profile: ``jit_engine_prefill``,
 # ``jit_engine_decode_step``, ``jit_engine_decode_window``,
-# ``jit_splice_slot``, ``jit_state_checksums``, ``jit_storage_verify``
-# (the deploy-time weight checksums stay ``jit_storage_checksums``).
+# ``jit_splice_slot``, ``jit_state_checksums``, ``jit_storage_verify``,
+# ``jit_snapshot_copy`` (the deploy-time weight checksums stay
+# ``jit_storage_checksums``).
+#
+# The decode programs and the splice take the live cache donated: each
+# updates it in place, and the caller's reference to the old cache is dead
+# once the call returns.  Whatever must outlive a call (a snapshot) holds
+# its own device copy (``snapshot_copy``).
 
 
 _storage_checksums = jax.jit(abft.storage_checksums)
@@ -126,11 +133,20 @@ def storage_verify(params, checks):
 
 
 @jax.jit
+def snapshot_copy(state):
+    """A device copy of ``state`` that owns its buffers: what a snapshot
+    keeps, and what a restore hands back to the engine, since the decode
+    programs consume the live cache they are given."""
+    return jax.tree_util.tree_map(jnp.copy, state)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
 def splice_slot(batch_cache, one_cache, tokens, slot, first_tok, n):
     """Join-time slot splice, fused into one compiled call: write the
     prefilled request's cache rows and first token into ``slot`` of the live
-    batch.  Module-level jit so every executor (and every fleet replica)
-    shares one compile cache entry per cache structure."""
+    batch (donated: updated in place).  Module-level jit so every executor
+    (and every fleet replica) shares one compile cache entry per cache
+    structure."""
     cache = model_api.cache_write_slot(batch_cache, one_cache, slot, n)
     return cache, tokens.at[slot].set(first_tok)
 
@@ -138,13 +154,14 @@ def splice_slot(batch_cache, one_cache, tokens, slot, first_tok, n):
 def decode_step_fn(cfg: ArchConfig):
     """The jitted decode step ``(params, tokens, cache) -> (next tokens,
     cache, stats)``: greedy next token of every slot, and the summed
-    DependabilityStats of the in-graph FFN checks the step ran."""
+    DependabilityStats of the in-graph FFN checks the step ran.  The cache
+    is donated."""
     def engine_decode_step(params, tokens, cache):
         with collect_stats() as sink:
             logits, cache = model_api.decode_step(cfg, params, tokens, cache)
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
                 sink.stats)
-    return jax.jit(engine_decode_step)
+    return jax.jit(engine_decode_step, donate_argnums=(2,))
 
 
 def prefill_fn(cfg: ArchConfig, max_len: int):
@@ -180,7 +197,8 @@ def _decode_window_fn(decode_fn, n_steps: int, eos_id: int, max_len: int):
     inner step (slot rows are independent and a later join splices whole
     rows), which is exactly the per-step engine's behavior for slots that
     finished but have not been re-filled yet.  Returns (carry, (next
-    tokens, finished masks), stats summed over the window's N steps).
+    tokens, finished masks), stats summed over the window's N steps).  The
+    cache is donated: the window updates it in place.
     """
     key = (id(decode_fn), n_steps, eos_id, max_len)
     hit = _DECODE_WINDOW_CACHE.get(key)
@@ -204,7 +222,7 @@ def _decode_window_fn(decode_fn, n_steps: int, eos_id: int, max_len: int):
             None, length=n_steps)
         return carry, emitted, stats
 
-    fn = jax.jit(engine_decode_window)
+    fn = jax.jit(engine_decode_window, donate_argnums=(2,))
     _DECODE_WINDOW_CACHE[key] = (decode_fn, fn)
     return fn
 
@@ -1355,7 +1373,8 @@ class StreamingExecutor:
     def _take_snapshot(self):
         d = self.decode
         self._snapshot = {
-            "cache": d.cache,
+            # its own copy: the next decode program consumes d.cache
+            "cache": snapshot_copy(d.cache),
             "tokens": d.tokens,
             "slot_pos": d.slot_pos.copy(),
             "slot_remaining": d.slot_remaining.copy(),
@@ -1398,7 +1417,9 @@ class StreamingExecutor:
                     "golden snapshot itself) — refusing to restore; escalate "
                     "to drain + failover")
         d = self.decode
-        d.cache = snap["cache"]
+        # a copy, so the snapshot survives the decode that consumes it and
+        # a second fault can roll back to it again
+        d.cache = snapshot_copy(snap["cache"])
         d.tokens = snap["tokens"]
         d.slot_pos = snap["slot_pos"].copy()
         d.slot_remaining = snap["slot_remaining"].copy()

@@ -215,10 +215,11 @@ def test_strike_decode_state_is_caught_by_scrub(smollm):
 def test_strike_kv_cache_and_weights_route_to_owners(smollm):
     cfg, params = smollm
     eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8)
-    before_cache = jax.tree_util.tree_leaves(eng.cache)
     before_params = jax.tree_util.tree_leaves(eng.params)
     eng.submit(Request(uid=0, prompt=[5, 9, 2], max_new_tokens=4))
     eng.step()
+    # host copies: the next decode consumes (donates) the live cache
+    before_cache = [np.asarray(x) for x in jax.tree_util.tree_leaves(eng.cache)]
     eng.strike("kv_cache", fi.flip_one_bit, jax.random.key(1))
     eng.strike("weights", fi.flip_one_bit, jax.random.key(2))
     after_cache = jax.tree_util.tree_leaves(eng.cache)
@@ -410,6 +411,89 @@ def test_multi_step_snapshot_rollback_still_bit_exact(smollm):
     events = eng.drain_state_events()
     assert len(events) == 1 and events[0]["recovered"]
     assert req.output == golden
+
+
+def _aliased_params(compiled_text: str) -> set:
+    """Entry parameters the compiled module aliases to an output."""
+    m = re.search(r"input_output_alias=\{(.*?) \}, ", compiled_text)
+    return {int(i) for i in re.findall(r": \((\d+),", m.group(1))} if m else set()
+
+
+@pytest.mark.parametrize("quant_kv", [True, False], ids=["int8kv", "float"])
+def test_decode_programs_update_the_cache_in_place(quant_kv):
+    """The decode window, the decode step and the join splice take the cache
+    donated and alias every cache leaf to an output, and the layer scan
+    emits the token's rows, (L, B, KV, hd) and (L, B, KV), not the
+    layers' (B, T, ...) pages: nothing re-stacks the cache."""
+    cfg = dataclasses.replace(reduced(registry.get("smollm-135m")),
+                              quant_kv=quant_kv)
+    params = model_api.init_params(cfg, jax.random.key(0))
+    B, max_len = 2, 96
+    cache = model_api.init_cache(cfg, B, max_len)
+    L, _, T, KV, hd = cache.k.shape
+    vec = jnp.zeros((B,), jnp.int32)
+
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c: model_api.decode_step(cfg, p, t, c))(params, vec,
+                                                              cache)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    ys = [v.aval.shape for v in scans[0].outvars[scans[0].params["num_carry"]:]]
+    assert ys == [(L, B, KV, hd)] * 2 + ([(L, B, KV)] * 2 if quant_kv else [])
+
+    n_params = len(jax.tree_util.tree_leaves(params))
+    n_cache = len(jax.tree_util.tree_leaves(cache))
+    decode = df.decode_step_fn(cfg)
+    window = df._decode_window_fn(decode, 2, -1, max_len)
+    one = model_api.init_cache(cfg, 1, max_len)
+    # (lowered program, index of its first cache parameter)
+    lowered = [
+        (window.lower(params, vec, cache, vec, vec, jnp.ones((B,), bool)),
+         n_params + 1),
+        (decode.lower(params, vec, cache), n_params + 1),
+        (df.splice_slot.lower(cache, one, vec, jnp.int32(0), jnp.int32(1),
+                              jnp.int32(3)), 0),
+    ]
+    for low, first in lowered:
+        hlo = low.compile().as_text()
+        assert set(range(first, first + n_cache)) <= _aliased_params(hlo)
+
+
+def test_donated_windows_roll_back_bit_exact_twice(smollm):
+    """A snapshot, two donated decode windows and a join splice, then a
+    rollback: the snapshot owns its copy of the cache, so the streams stay
+    bit-exact with an undisturbed run, the snapshot still verifies after
+    the restore, and a second rollback to it works too."""
+    cfg, params = smollm
+    prompts, budgets = [[5, 9, 2], [3, 1, 4, 1]], [14, 6]
+
+    def serve(rollbacks):
+        eng = Engine(cfg, params, capacity=2, max_len=96, prefill_pad=8,
+                     multi_step=2, snapshot_every=100,
+                     state_scrub="rollback")
+        reqs = [Request(uid=i, prompt=list(p), max_new_tokens=n)
+                for i, (p, n) in enumerate(zip(prompts, budgets))]
+        eng.submit(reqs[0])
+        eng.step()                     # join, snapshot, window 1
+        eng.step()                     # window 2
+        eng.submit(reqs[1])
+        eng.step()                     # join splice, window 3
+        ex = eng.executor
+        for _ in range(rollbacks):
+            assert eng.restore_snapshot() > 0
+            snap = ex._snapshot
+            assert df._checks_equal(
+                df.state_checksums({"cache": snap["cache"],
+                                    "tokens": snap["tokens"]}),
+                snap["check"])
+            eng.step()
+        eng.run()
+        assert eng.drain_state_events() == []
+        return [list(r.output) for r in reqs]
+
+    golden = serve(0)
+    assert golden[0] == greedy_reference(cfg, params, prompts[0], budgets[0])
+    assert serve(2) == golden
 
 
 def test_failover_bit_exact_hybrid_family():
@@ -669,6 +753,7 @@ def test_engine_programs_have_stable_module_names(smollm):
         "jit_storage_verify": df.storage_verify.lower(
             params, ex._storage_checks),
         "jit_storage_checksums": df._storage_checksums.lower(params),
+        "jit_snapshot_copy": df.snapshot_copy.lower(eng.cache),
     }
     for name, low in lowered.items():
         assert f"module @{name} " in low.as_text(), name

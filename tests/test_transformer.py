@@ -93,9 +93,11 @@ def test_grad_flows_and_finite():
     assert np.abs(g).max() > 0
 
 
-@pytest.mark.parametrize("cfg", [small_dense(), small_dense(swa_window=8),
-                                 small_moe()],
-                         ids=["dense", "swa", "moe"])
+@pytest.mark.parametrize("cfg", [
+    small_dense(), small_dense(swa_window=8), small_moe(),
+    small_dense(quant_kv=True), small_dense(swa_window=8, quant_kv=True),
+    small_moe(quant_kv=True),
+], ids=["dense", "swa", "moe", "dense-int8kv", "swa-int8kv", "moe-int8kv"])
 def test_decode_matches_forward(cfg):
     """Teacher-forced decode step-by-step must reproduce forward() logits."""
     params = tfm.init_params(cfg, jax.random.key(0))
@@ -109,9 +111,12 @@ def test_decode_matches_forward(cfg):
         logits, cache = tfm.decode_step(cfg, params, tokens[:, t], cache)
         outs.append(logits)
     dec = jnp.stack(outs, axis=1)
+    # an int8 K/V row is off by up to half a step of its row max / 127, so
+    # its logits leave the float forward's by up to ~0.1 at these sizes
+    tol = 0.1 if cfg.quant_kv else 3e-2
     np.testing.assert_allclose(np.asarray(dec, np.float32),
                                np.asarray(full.logits, np.float32),
-                               rtol=3e-2, atol=3e-2)
+                               rtol=tol, atol=tol)
 
 
 def test_prefill_then_decode_continues_correctly():
@@ -133,9 +138,12 @@ def test_prefill_then_decode_continues_correctly():
                                rtol=3e-2, atol=3e-2)
 
 
-def test_swa_ring_buffer_decode_long():
-    """Decoding past the window: ring buffer must match forward() with SWA."""
-    cfg = small_dense(swa_window=8)
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["float", "int8kv"])
+def test_swa_ring_buffer_decode_long(quant_kv):
+    """Decoding past the window: ring buffer must match forward() with SWA.
+    Each step past the window attends before it overwrites the oldest slot,
+    which must then drop out of the softmax."""
+    cfg = small_dense(swa_window=8, quant_kv=quant_kv)
     params = tfm.init_params(cfg, jax.random.key(0))
     B, S = 1, 20                      # > 2× window
     tokens = jax.random.randint(jax.random.key(1), (B, S), 0, cfg.vocab_size)
@@ -151,6 +159,39 @@ def test_swa_ring_buffer_decode_long():
     np.testing.assert_allclose(np.asarray(dec, np.float32),
                                np.asarray(full.logits, np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+@pytest.mark.parametrize("quant_kv", [False, True], ids=["float", "int8kv"])
+@pytest.mark.parametrize("pos", [[3, 5], [9, 21]], ids=["filling", "wrapped"])
+def test_attend_then_write_matches_write_then_attend(quant_kv, pos):
+    """``decode_attention`` over the unmodified page plus the token's own
+    row (``new``) equals attention over the page with the row written at
+    ``pos % T``: the same scores and weights, only the value sum's order
+    differs.  ``wrapped`` rows have filled the ring, so the overwritten
+    (oldest) entry must drop out."""
+    from repro.models import common
+    B, T, KV, H, hd = 2, 8, 2, 4, 16
+    keys = jax.random.split(jax.random.key(0), 5)
+    q = jax.random.normal(keys[0], (B, 1, H, hd))
+    k_page = jax.random.normal(keys[1], (B, T, KV, hd))
+    v_page = jax.random.normal(keys[2], (B, T, KV, hd))
+    k_row = jax.random.normal(keys[3], (B, KV, hd))
+    v_row = jax.random.normal(keys[4], (B, KV, hd))
+    pos = jnp.asarray(pos)
+    slot, valid, b = pos % T, jnp.minimum(pos + 1, T), jnp.arange(B)
+    if quant_kv:
+        (kq, ksc), (vq, vsc) = map(tfm._quantize_kv_rows, (k_page, v_page))
+        (kr, krs), (vr, vrs) = map(tfm._quantize_kv_rows, (k_row, v_row))
+        pages, rows = [kq, vq, ksc, vsc], [kr, vr, krs, vrs]
+    else:
+        pages, rows = [k_page, v_page], [k_row, v_row]
+    written = [p.at[b, slot].set(r) for p, r in zip(pages, rows)]
+    want = common.decode_attention(q, written[0], written[1], valid,
+                                   *written[2:])
+    got = common.decode_attention(q, pages[0], pages[1], valid, *pages[2:],
+                                  new=(slot, *rows))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_embedding_input_mode():
